@@ -200,6 +200,8 @@ def main() -> None:
                          "committed BENCH_<suite>.json speedup baseline")
     args = ap.parse_args()
 
+    from repro.kernels.common.runtime import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_add, bench_breakdown, bench_crypto,
                             bench_div, bench_exact_accum, bench_gmp,
                             bench_mul, bench_roofline, bench_serve)

@@ -309,11 +309,13 @@ def configure(*, mul_method=_UNSET, div_method=_UNSET,
         identities and mod_exp / engine crypto results against host
         witnesses; failures tick ``selfcheck_failures_total`` under
         every policy (see repro/resilience/selfcheck.py),
-      * ``kernel_fallback`` bool -- True/None (default) degrades a
-        failing Pallas tier through jnp to the host reference so every
-        request still answers; False is strict mode (the first kernel
-        failure propagates -- what CI uses to catch regressions that
-        silent degradation would hide, see repro/resilience/guard.py).
+      * ``kernel_fallback`` bool -- False/None (the default) is strict
+        mode: the first kernel failure propagates, so a kernel that
+        does not lower is an error, never a silent jnp run; True
+        degrades a failing Pallas tier through jnp to the host
+        reference (and lets the serving engine demote a failing
+        bucket) so every request still answers, see
+        repro/resilience/guard.py.
 
     Returns a context manager: ``with configure(...):`` restores the
     previous values on exit; a bare call applies them permanently.
@@ -387,7 +389,7 @@ def cache_stats() -> dict:
     """Hit/miss/size counters for every process-level arithmetic cache:
 
       * ``twiddle``  -- the lru_cache of per-(prime, N) NTT twiddle
-        tables (kernels/ntt_mul.twiddle_tables),
+        tables (kernels/ntt_mul.lane_twiddles),
       * ``operand``  -- the prepared-operand NTT cache (forward
         transforms of host-known constants, LRU-bounded by
         ``configure(ntt_cache_entries=...)``),
@@ -412,7 +414,7 @@ def cache_stats() -> dict:
                 "entries": info.currsize, "capacity": info.maxsize}
 
     return {
-        "twiddle": _lru(_nops.twiddle_tables.cache_info()),
+        "twiddle": _lru(_nops.lane_twiddles.cache_info()),
         "operand": _nops.operand_cache_stats(),
         "autotune": _at.cache_stats(),
         "ctx": {

@@ -39,8 +39,9 @@ OVERRIDE_NAMES = ("mul_method", "div_method", "modexp_backend", "autotune",
 # ``selfcheck`` arms residue/witness result verification (None/False
 # off, "warn" / "raise" policies, see repro/resilience/selfcheck.py);
 # ``kernel_fallback`` gates degradation through the guarded kernel
-# tiers (None/True degrade, False strict -- first failure propagates,
-# see repro/resilience/guard.py).
+# tiers and the serving engine (True degrades; None/False, the default,
+# is strict -- the first failure propagates, see
+# repro/resilience/guard.py).
 ENV_ALIASES = {
     "mul_method": "REPRO_MUL_BACKEND",
     "div_method": "REPRO_DIV_BACKEND",

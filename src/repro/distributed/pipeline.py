@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, microbatches: int,
                      axis_name: str = "stage"):
@@ -74,10 +72,11 @@ def run_pipelined(mesh: Mesh, stage_fn, stage_params_stacked, x,
     x_mb = x.reshape(microbatches, B // microbatches, *x.shape[1:])
 
     fn = pipeline_forward(stage_fn, S, microbatches, axis_name)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis_name), P()),      # params sharded by stage
         out_specs=P(),
     )
-    out_mb = mapped(stage_params_stacked, x_mb)
+    with jax.set_mesh(mesh):
+        out_mb = mapped(stage_params_stacked, x_mb)
     return out_mb.reshape(B, *x.shape[1:])

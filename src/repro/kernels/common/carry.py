@@ -12,13 +12,44 @@ unrolled at trace time), which is what makes them kernel-safe: inside a
 ``pallas_call`` body there is no ``lax.while_loop`` over a data-dependent
 carry count, so convergence bounds must be proven at build time instead
 of checked at run time.
+
+The Mosaic TPU compiler lowers neither scatter (``x.at[...].add``) nor
+``dynamic_slice``, so kernels place lane windows with ``add_at`` (a
+zero-padded concatenate plus an add) and touch lane 0 with ``add_lane0``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 U32 = jnp.uint32
+
+
+def add_at(dst, start: int, src):
+    """dst[..., start:start+w] += src for a static start, as a full-width
+    add of src zero-padded along the last axis (kernel-safe: no scatter)."""
+    width, w = dst.shape[-1], src.shape[-1]
+    rest = width - start - w
+    assert start >= 0 and rest >= 0, "window outside the destination"
+    lead = src.shape[:-1]
+    parts = ([jnp.zeros(lead + (start,), src.dtype)] if start else []) \
+        + [src] + ([jnp.zeros(lead + (rest,), src.dtype)] if rest else [])
+    return dst + (jnp.concatenate(parts, axis=-1) if len(parts) > 1
+                  else src)
+
+
+def add_lane0(x, v):
+    """x with v added to digit 0 of the last axis (v a scalar or (..., 1))."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return x + jnp.where(lane == 0, v, np.uint32(0)).astype(x.dtype)
+
+
+def rotate_down(x):
+    """Digit i+1 moves to lane i and digit 0 wraps to the top: reading
+    lane 0 after k rotations yields digit k, a static slice where a
+    ``fori_loop`` would otherwise need a dynamic lane slice."""
+    return jnp.concatenate([x[..., 1:], x[..., :1]], axis=-1)
 
 
 def ks_scan_unrolled(g, p):

@@ -34,6 +34,10 @@ def budget_words(live_u32_arrays: int,
 def batch_tile(m: int, batch: int, *, budget: int,
                max_tile: int = DEFAULT_MAX_TILE,
                min_tile: int = MIN_TILE) -> int:
-    """Heuristic batch tile for a kernel over (batch, m) digit arrays."""
+    """Heuristic batch tile for a kernel over (batch, m) digit arrays.
+
+    A multiple of ``min_tile``: the TPU compiler only accepts a block
+    whose sublane dimension is a multiple of 8 (or the whole array)."""
     tb = max(min_tile, min(max_tile, budget // max(min_tile, m)))
-    return min(tb, max(min_tile, batch))
+    tb = min(tb, max(min_tile, batch))
+    return max(min_tile, tb // min_tile * min_tile)

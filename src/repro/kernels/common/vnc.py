@@ -19,6 +19,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common.carry import add_at
+
 U32 = jnp.uint32
 DBITS = 16
 DMASK = np.uint32((1 << DBITS) - 1)
@@ -50,7 +52,7 @@ def vnc_cols_rows(a, b):
         prod = a[..., i:i + 1] * b               # exact uint32 products
         row = (jnp.concatenate([prod & DMASK, z1], axis=-1)
                + jnp.concatenate([z1, prod >> np.uint32(DBITS)], axis=-1))
-        cols = cols.at[..., i:i + nb + 1].add(row)   # lo at c, hi at c+1
+        cols = add_at(cols, i, row)              # lo at c, hi at c+1
     return cols
 
 

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.common.carry import normalize_static
+from repro.kernels.common.carry import add_lane0, normalize_static
 
 U32 = jnp.uint32
 DMASK = np.uint32(0xFFFF)
@@ -63,7 +63,8 @@ def _sub_flag(r, t):
     tb, w = r.shape
     comp = DMASK - t
     s = jnp.concatenate([r + comp, jnp.zeros((tb, 1), U32)], axis=1)
-    s = normalize_static(s.at[:, 0:1].add(1), 16, bound=(1 << 17) + 2)
+    s = normalize_static(add_lane0(s, np.uint32(1)), 16,
+                         bound=(1 << 17) + 2)
     return s[:, :w], s[:, w:w + 1]
 
 
@@ -80,12 +81,13 @@ def div_step(r, ain, b, b_top):
     r = jnp.concatenate([ain, r[:, :nb]], axis=1)
     # P2: two-digit trial estimate, clamped to the digit range.
     num = (r[:, nb:nb + 1] << DBITS) | r[:, nb - 1:nb]
-    qh = jnp.minimum(num // b_top, DMASK)
+    qh = num // b_top
+    qh = jnp.where(qh > DMASK, DMASK, qh)       # (Mosaic has no unsigned min)
     # P3: r - qh*b with lazy products and one static resolve.
     prod = qh * b                                   # (TB, nb) exact uint32
-    t = jnp.zeros((tb, nb + 1), U32)
-    t = t.at[:, :nb].add(prod & DMASK)
-    t = t.at[:, 1:nb + 1].add(prod >> DBITS)
+    z1 = jnp.zeros((tb, 1), U32)
+    t = (jnp.concatenate([prod & DMASK, z1], axis=1)
+         + jnp.concatenate([z1, prod >> DBITS], axis=1))
     t = normalize_static(t, 16, bound=1 << 17)      # qh*b, < D**(nb+1)
     u, ge = _sub_flag(r, t)
     # P4: at most two add-backs (Knuth: qh <= q + 2, never < q).
@@ -110,13 +112,24 @@ def make_div_kernel(wa: int, nb: int):
         a = a_ref[...]                              # (TB, wa) shifted dividend
         b = b_ref[...]                              # (TB, nb) normalized
         tb = a.shape[0]
-        b_top = jnp.maximum(b[:, nb - 1:nb], 1)     # mask zero divisors
-        r = jnp.zeros((tb, nb + 1), U32)
-        qcols = []
-        for t in range(wa):                         # MSB-first digit serial
-            r, qh = div_step(r, a[:, wa - 1 - t:wa - t], b, b_top)
-            qcols.append(qh)
-        q_ref[...] = jnp.concatenate(qcols[::-1], axis=1)
+        b_top = b[:, nb - 1:nb]
+        b_top = jnp.where(b_top == 0, np.uint32(1), b_top)  # mask zero divisors
+
+        def step(_, carry):
+            # MSB-first digit serial: the next dividend digit sits in the
+            # top lane of a copy of a rotated up once per step, and each
+            # quotient digit enters q at lane 0 (static slices only)
+            r, q, a_rot = carry
+            r, qh = div_step(r, a_rot[:, wa - 1:wa], b, b_top)
+            q = jnp.concatenate([qh, q[:, :wa - 1]], axis=1)
+            a_rot = jnp.concatenate([a_rot[:, wa - 1:], a_rot[:, :wa - 1]],
+                                    axis=1)
+            return r, q, a_rot
+
+        r, q, _ = jax.lax.fori_loop(
+            0, wa, step,
+            (jnp.zeros((tb, nb + 1), U32), jnp.zeros((tb, wa), U32), a))
+        q_ref[...] = q
         r_ref[...] = r[:, :nb]
 
     return div_kernel

@@ -30,7 +30,7 @@ exponentiation (Montgomery entry, 2**w-entry power table build, all
 squarings and branch-free one-hot table selects, Montgomery exit) with
 everything VMEM-resident -- versus two launches per exponent bit when
 the ladder is composed outside the kernel.  Its loops are
-lax.fori_loops (see cios_iterations_loop) so compile time stays flat
+lax.fori_loops (see cios_iterations) so compile time stays flat
 in nbits.
 """
 from __future__ import annotations
@@ -42,7 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.common.carry import normalize_static
+from repro.kernels.common.carry import (add_lane0, normalize_static,
+                                        rotate_down)
 
 U32 = jnp.uint32
 DMASK = np.uint32(0xFFFF)
@@ -53,32 +54,6 @@ DBITS = np.uint32(16)
 # common/tiling.
 LIVE_U32_ARRAYS = 12
 MAX_TILE = 256
-
-
-def cios_iterations(a, b, n, n0p):
-    """The lazy CIOS loop on (TB, m) blocks; returns the (TB, m+1) lazy
-    accumulator with t = a*b*R^{-1} represented in deferred-carry digits.
-
-    Unrolled over the m digits of a (the dependency chain inherent to
-    Montgomery); every line is a full-width VPU op over the batch tile.
-    """
-    tb, m = a.shape
-    n0p = np.uint32(n0p)
-    acc = jnp.zeros((tb, m + 1), U32)
-    for i in range(m):
-        prod = a[:, i:i + 1] * b                  # exact uint32 products
-        acc = acc.at[:, :m].add(prod & DMASK)
-        acc = acc.at[:, 1:m + 1].add(prod >> DBITS)
-        u = ((acc[:, 0:1] & DMASK) * n0p) & DMASK
-        prod2 = u * n                             # (TB, m), exact uint32
-        acc = acc.at[:, :m].add(prod2 & DMASK)
-        acc = acc.at[:, 1:m + 1].add(prod2 >> DBITS)
-        # digit 0 is now 0 mod B: shift down, carrying its high part
-        c0 = acc[:, 0:1] >> DBITS
-        acc = jnp.concatenate(
-            [acc[:, 1:], jnp.zeros((tb, 1), U32)], axis=1)
-        acc = acc.at[:, 0:1].add(c0)
-    return acc
 
 
 def cond_subtract(t, n):
@@ -92,49 +67,52 @@ def cond_subtract(t, n):
     m = t.shape[1] - 1
     comp = jnp.concatenate(
         [DMASK - n, jnp.full((n.shape[0], 1), DMASK, U32)], axis=1)
-    s = (t + comp).at[:, 0:1].add(1)              # lazy, < 2**17 + 1
+    s = add_lane0(t + comp, np.uint32(1))        # lazy, < 2**17 + 1
     ext = jnp.concatenate([s, jnp.zeros((tb, 1), U32)], axis=1)
     sn = normalize_static(ext)                    # (TB, m+2)
     ge = sn[:, m + 1:m + 2]                       # carry out: 1 iff t >= n
     return jnp.where(ge == 1, sn[:, :m], t[:, :m])
 
 
-def cios_iterations_loop(a, b, n, n0p):
-    """cios_iterations with the digit loop as a lax.fori_loop instead of
-    a trace-time unroll.
+def cios_iterations(a, b, n, n0p):
+    """The lazy CIOS loop on (TB, m) blocks; returns the (TB, m+1) lazy
+    accumulator with t = a*b*R^{-1} represented in deferred-carry digits.
 
-    Semantically identical; used by the fused ladder kernel, where the
-    unrolled form would inline m iterations into EVERY one of the
-    ~nbits*(1+1/w) multiplies of the window loop body and blow up
-    compile time.  The single-multiply kernel keeps the unrolled form
-    (static slices, nothing else in the launch to amortize against).
+    The digit loop (the dependency chain inherent to Montgomery) is a
+    lax.fori_loop, so compile time stays flat in m and the ladder kernel
+    does not inline m iterations into each of its ~nbits*(1+1/w)
+    multiplies.  Digit a_i is read from lane 0 of a copy of a rotated
+    once per iteration (Mosaic has no dynamic lane slice).
     """
     tb, m = a.shape
     n0p = np.uint32(n0p)
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, (tb, m + 1), 1) == 0
+    z1 = jnp.zeros((tb, 1), U32)
 
-    def body(i, acc):
-        ai = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)   # (TB, 1)
-        prod = ai * b                             # exact uint32 products
-        acc = acc.at[:, :m].add(prod & DMASK)
-        acc = acc.at[:, 1:m + 1].add(prod >> DBITS)
+    def body(_, carry):
+        acc, a_rot = carry
+        prod = a_rot[:, 0:1] * b                  # exact uint32 products
+        acc = (acc + jnp.concatenate([prod & DMASK, z1], axis=1)
+               + jnp.concatenate([z1, prod >> DBITS], axis=1))
         u = ((acc[:, 0:1] & DMASK) * n0p) & DMASK
         prod2 = u * n                             # (TB, m), exact uint32
-        acc = acc.at[:, :m].add(prod2 & DMASK)
-        acc = acc.at[:, 1:m + 1].add(prod2 >> DBITS)
+        acc = (acc + jnp.concatenate([prod2 & DMASK, z1], axis=1)
+               + jnp.concatenate([z1, prod2 >> DBITS], axis=1))
+        # digit 0 is now 0 mod B: shift down, carrying its high part
         c0 = acc[:, 0:1] >> DBITS
-        acc = jnp.concatenate(
-            [acc[:, 1:], jnp.zeros((tb, 1), U32)], axis=1)
-        acc = acc.at[:, 0:1].add(c0)
-        return acc
+        acc = (jnp.concatenate([acc[:, 1:], z1], axis=1)
+               + jnp.where(lane0, c0, np.uint32(0)))
+        return acc, rotate_down(a_rot)
 
-    return jax.lax.fori_loop(0, m, body, jnp.zeros((tb, m + 1), U32))
+    acc, _ = jax.lax.fori_loop(0, m, body, (jnp.zeros((tb, m + 1), U32), a))
+    return acc
 
 
 def mont_mul_block(a, b, n, n0p):
     """Full normalized Montgomery product on (TB, m) blocks (loop CIOS +
     carry resolve + branch-free conditional subtract) -- the multiply
     the fused ladder kernel composes ~nbits*(1+1/w) times per launch."""
-    acc = cios_iterations_loop(a, b, n, n0p)
+    acc = cios_iterations(a, b, n, n0p)
     return cond_subtract(normalize_static(acc), n)
 
 
@@ -145,11 +123,20 @@ def make_mont_kernel(m: int, n0p: int):
         a = a_ref[...]                            # (TB, m) digits < 2**16
         b = b_ref[...]
         n = n_ref[...]                            # (1, m) modulus digits
-        acc = cios_iterations(a, b, n, n0p)
-        t = normalize_static(acc)                 # single deferred resolve
-        out_ref[...] = cond_subtract(t, n)
+        out_ref[...] = mont_mul_block(a, b, n, n0p)
 
     return mont_mul_kernel
+
+
+def select_window(table, wins):
+    """Branch-free table lookup: table[d] per lane for d = wins[:, 0],
+    as a chain of 2**w selects over the (TB, m) power-table entries (the
+    window value steers data, never control flow)."""
+    d = wins[:, 0:1]                              # (TB, 1)
+    out = table[0]
+    for k in range(1, len(table)):
+        out = jnp.where(d == k, table[k], out)
+    return out
 
 
 def ladder_live_arrays(window: int) -> int:
@@ -178,7 +165,6 @@ def make_ladder_kernel(m: int, n0p: int, window: int, nwin: int):
         base = base_ref[...]                      # (TB, m) digits < 2**16
         wins = win_ref[...]                       # (TB, nwin) window values
         n = n_ref[...]                            # (1, m) modulus digits
-        tb = base.shape[0]
 
         def mm(x, y):
             return mont_mul_block(x, y, n, n0p)
@@ -187,20 +173,16 @@ def make_ladder_kernel(m: int, n0p: int, window: int, nwin: int):
         table = [jnp.broadcast_to(one_ref[...], base.shape), x]
         for _ in range(2, nt):
             table.append(mm(table[-1], x))
-        tab = jnp.stack(table[:nt])               # (2**w, TB, m) in VMEM
-        iota = jax.lax.broadcasted_iota(U32, (nt, tb), 0)
 
-        def select(j):
-            d = jax.lax.dynamic_slice_in_dim(wins, j, 1, axis=1)  # (TB, 1)
-            onehot = (iota == d.reshape(1, tb)).astype(U32)       # (2**w, TB)
-            return jnp.sum(tab * onehot[:, :, None], axis=0)      # (TB, m)
-
-        def win_step(j, res):
+        def win_step(_, carry):
+            res, w_rot = carry
             for _ in range(window):
                 res = mm(res, res)
-            return mm(res, select(j))
+            return mm(res, select_window(table, w_rot)), rotate_down(w_rot)
 
-        res = jax.lax.fori_loop(1, nwin, win_step, select(0))
+        res, _ = jax.lax.fori_loop(
+            1, nwin, win_step,
+            (select_window(table, wins), rotate_down(wins)))
         plain_one = (jax.lax.broadcasted_iota(U32, (1, m), 1) == 0)
         out_ref[...] = mm(res, jnp.broadcast_to(plain_one.astype(U32),
                                                 base.shape))      # exit Mont
@@ -247,20 +229,31 @@ def full_mul_columns(a, b):
     The schoolbook column accumulation of kernels/dot_mul, restated as a
     lax.fori_loop over a's digits so the fused Barrett ladder (three of
     these per modular multiply, ~nbits*(1+1/w) multiplies per launch)
-    traces one body instead of inlining ma iterations everywhere."""
+    traces one body instead of inlining ma iterations everywhere.
+
+    Iteration i adds row a_i*b to an (mb+1)-digit window whose lane 0 is
+    column i; column i is then complete, so it is shifted into the top
+    of ``low`` and the window moves down one digit.  After ma steps
+    ``low`` holds columns 0..ma-1 in order.  Every slice is static (a_i
+    comes from lane 0 of a rotated copy of a): Mosaic lowers neither
+    dynamic lane slices nor dynamic_update_slice."""
     tb, ma = a.shape
     mb = b.shape[1]
     zeros1 = jnp.zeros((tb, 1), U32)
 
-    def body(i, acc):
-        ai = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)   # (TB, 1)
-        prod = ai * b                             # exact uint32 products
-        contrib = (jnp.concatenate([prod & DMASK, zeros1], axis=1)
-                   + jnp.concatenate([zeros1, prod >> DBITS], axis=1))
-        cur = jax.lax.dynamic_slice(acc, (0, i), (tb, mb + 1))
-        return jax.lax.dynamic_update_slice(acc, cur + contrib, (0, i))
+    def body(_, carry):
+        acc, low, a_rot = carry
+        prod = a_rot[:, 0:1] * b                  # exact uint32 products
+        acc = (acc + jnp.concatenate([prod & DMASK, zeros1], axis=1)
+               + jnp.concatenate([zeros1, prod >> DBITS], axis=1))
+        low = jnp.concatenate([low[:, 1:], acc[:, 0:1]], axis=1)
+        acc = jnp.concatenate([acc[:, 1:], zeros1], axis=1)
+        return acc, low, rotate_down(a_rot)
 
-    return jax.lax.fori_loop(0, ma, body, jnp.zeros((tb, ma + mb), U32))
+    acc, low, _ = jax.lax.fori_loop(
+        0, ma, body,
+        (jnp.zeros((tb, mb + 1), U32), jnp.zeros((tb, ma), U32), a))
+    return jnp.concatenate([low, acc[:, :mb]], axis=1)
 
 
 def cond_sub_ge(r, n):
@@ -269,7 +262,7 @@ def cond_sub_ge(r, n):
     complement trick as cond_subtract, keeping all mw digits (Barrett's
     r < 3n needs m+1 digits until the final correction lands)."""
     tb, mw = r.shape
-    s = (r + (DMASK - n)).at[:, 0:1].add(1)       # lazy, <= 2**17 + 1
+    s = add_lane0(r + (DMASK - n), np.uint32(1))  # lazy, <= 2**17 + 1
     ext = jnp.concatenate([s, jnp.zeros((tb, 1), U32)], axis=1)
     sn = normalize_static(ext, bound=1 << 17)     # (TB, mw+1)
     ge = sn[:, mw:mw + 1]                         # carry out: 1 iff r >= n
@@ -301,7 +294,7 @@ def barrett_mul_block(a, b, n, mu):
     p = normalize_static(full_mul_columns(q, n),
                          bound=(2 * (m + 1)) << 16)  # q_hat*n <= x < B**2m
     # r = x - p on m+1 digits: exact mod B**(m+1) because 0 <= x-p < 3n
-    s = (x[:, :m + 1] + (DMASK - p[:, :m + 1])).at[:, 0:1].add(1)
+    s = add_lane0(x[:, :m + 1] + (DMASK - p[:, :m + 1]), np.uint32(1))
     r = normalize_static(s, bound=1 << 17)        # carry past top drops
     n_ext = jnp.concatenate([n, jnp.zeros((1, 1), U32)], axis=1)
     r = cond_sub_ge(r, n_ext)
@@ -339,7 +332,6 @@ def make_barrett_ladder_kernel(m: int, window: int, nwin: int):
         wins = win_ref[...]                       # (TB, nwin) window values
         n = n_ref[...]                            # (1, m) modulus digits
         mu = mu_ref[...]                          # (1, m+2) mu digits
-        tb = base.shape[0]
 
         def mm(x, y):
             return barrett_mul_block(x, y, n, mu)
@@ -348,20 +340,16 @@ def make_barrett_ladder_kernel(m: int, window: int, nwin: int):
         table = [jnp.broadcast_to(one, base.shape), base]
         for _ in range(2, nt):
             table.append(mm(table[-1], base))
-        tab = jnp.stack(table[:nt])               # (2**w, TB, m) in VMEM
-        iota = jax.lax.broadcasted_iota(U32, (nt, tb), 0)
 
-        def select(j):
-            d = jax.lax.dynamic_slice_in_dim(wins, j, 1, axis=1)  # (TB, 1)
-            onehot = (iota == d.reshape(1, tb)).astype(U32)       # (2**w, TB)
-            return jnp.sum(tab * onehot[:, :, None], axis=0)      # (TB, m)
-
-        def win_step(j, res):
+        def win_step(_, carry):
+            res, w_rot = carry
             for _ in range(window):
                 res = mm(res, res)
-            return mm(res, select(j))
+            return mm(res, select_window(table, w_rot)), rotate_down(w_rot)
 
-        out_ref[...] = jax.lax.fori_loop(1, nwin, win_step, select(0))
+        out_ref[...], _ = jax.lax.fori_loop(
+            1, nwin, win_step,
+            (select_window(table, wins), rotate_down(wins)))
 
     return ladder_kernel
 

@@ -7,9 +7,10 @@ One program multiplies a (TB,) batch tile of m-digit operands (radix
                 (vectorized over the batch tile; every row independent).
   P2 products : one uint32 VPU multiply per row + lo/hi mask/shift
                 (exactly simd_mul_lo / simd_mul_hi).
-  P3 align    : static slice-adds place lo at columns [i, i+m) and hi at
-                [i+1, i+m+1) -- the skew without data movement.
-  P4 reduce   : the slice-adds ARE the column reduction (deferred carries;
+  P3 align    : static zero-padded adds place lo at columns [i, i+m) and
+                hi at [i+1, i+m+1) -- the skew without data movement
+                (common/vnc.vnc_cols_rows).
+  P4 reduce   : those adds ARE the column reduction (deferred carries;
                 column sums < 2m * 2**16 << 2**32, provably no overflow).
   P5 carry    : two deferred-carry passes bring digits to <= 2**16, then
                 an unrolled Kogge-Stone tail resolves the 0/1 residue --
@@ -20,14 +21,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels.common.carry import normalize_static
+from repro.kernels.common.vnc import vnc_cols_rows
 
 U32 = jnp.uint32
-DMASK = np.uint32(0xFFFF)
-DBITS = np.uint32(16)
 
 # The (TB, 2m) column accumulator plus operands, products, and the
 # normalize temps -- counted in (TB, m)-array equivalents for the
@@ -39,15 +38,7 @@ MAX_TILE = 256
 def mul_kernel(a_ref, b_ref, p_ref):
     a = a_ref[...]                           # (TB, m) digits < 2**16
     b = b_ref[...]
-    tb, m = a.shape
-    cols = jnp.zeros((tb, 2 * m), U32)
-    for i in range(m):                       # m independent rows, unrolled
-        prod = a[:, i:i + 1] * b             # P2: exact uint32 products
-        lo = prod & DMASK
-        hi = prod >> DBITS
-        cols = cols.at[:, i:i + m].add(lo)           # P3/P4
-        cols = cols.at[:, i + 1:i + m + 1].add(hi)
-    p_ref[...] = normalize_static(cols)      # P5
+    p_ref[...] = normalize_static(vnc_cols_rows(a, b))   # P2-P4, then P5
 
 
 def make_call(batch_tile: int, m: int, grid: int, interpret: bool):

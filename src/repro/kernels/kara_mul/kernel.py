@@ -26,7 +26,7 @@ Three tricks make that possible:
 
 2. **Batched base cases.**  The recursion is resolved at trace time into
    its 3^depth leaf multiplies, whose operands (halves and normalized
-   half-sums) are gathered into one (TB, P, nb) tensor; a single VnC row
+   half-sums) are gathered into one (P*TB, nb) block; a single VnC row
    loop of nb unrolled steps computes ALL leaf products at once (the
    multiplicative twin of batching independent adds over VPU lanes).
 
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.common.carry import normalize_static
+from repro.kernels.common.carry import add_at, normalize_static
 from repro.kernels.common.vnc import vnc_cols_rows, vnc_cols_skew
 
 U32 = jnp.uint32
@@ -68,9 +68,21 @@ DEFAULT_THRESHOLD = 48
 MAX_DIGITS = 256            # 4096 bits; bound analysis above covers <= 256
 
 # Leaf cols + stacked operands + recombination temps, in (TB, m)-array
-# equivalents (P*nb ~ (3/2)^depth * m, cols twice that, plus slices).
+# equivalents at depth 0 (see live_arrays for the growth with depth).
 LIVE_U32_ARRAYS = 24
 MAX_TILE = 128
+
+
+def live_arrays(m: int, threshold: int = DEFAULT_THRESHOLD) -> int:
+    """LIVE_U32_ARRAYS scaled by the leaf blow-up: each Karatsuba level
+    turns an n-digit operand into three of ~n/2 digits, so the stacked
+    leaves and their columns grow as (3/2)**depth (a 2048-bit tile sized
+    for depth 0 overruns the TPU compiler's 16 MiB scoped VMEM)."""
+    live = LIVE_U32_ARRAYS
+    while m > threshold:
+        m = -(-m // 2)
+        live = live * 3 // 2
+    return live
 
 
 def _ones_value(length: int) -> int:
@@ -120,55 +132,45 @@ def _collect(x, y, threshold, leaves):
     return ("split", n, k, s0, s1, ss)
 
 
-# Phase B (all base multiplies at once, (TB, P, nb) x2 -> (TB, P, 2nb)
+# Phase B (all base multiplies at once, (P*TB, nb) x2 -> (P*TB, 2nb)
 # lazy cols): two schedules of the same math, picked per backend -- the
 # row loop is the VPU-native form for TPU, the skew contraction avoids
 # the serial update chain that dominates in CPU interpret mode.
 _BASE_MODES = {"rows": vnc_cols_rows, "skew": vnc_cols_skew}
 
 
-def _slice_add(dst, start: int, src):
-    """dst[:, start:start+w] += src, as a plain add when the slice covers
-    the whole axis (a full-axis .at[].add lowers to a scatter with an
-    empty index constant, which pallas kernels cannot capture)."""
-    w = src.shape[1]
-    if start == 0 and w == dst.shape[1]:
-        return dst + src
-    return dst.at[:, start:start + w].add(src)
-
-
-def _combine(spec, cols):
+def _combine(spec, cols, tb: int):
     """Trace-time recursion, phase C: lazy recombination.
 
+    cols holds leaf idx's product columns in rows [idx*tb, (idx+1)*tb).
     Returns (lazy_cols (TB, L), bound, const) with
     value(lazy_cols) == true_product + const, const a static Python int.
     """
     if spec[0] == "leaf":
         _, w, idx = spec
-        return cols[:, idx, :2 * w], _leaf_bound(w), 0
+        return cols[idx * tb:(idx + 1) * tb, :2 * w], _leaf_bound(w), 0
 
     _, n, k, s0, s1, ss = spec
-    c0, b0, k0c = _combine(s0, cols)
-    c1, b1, k1c = _combine(s1, cols)
-    cs, bs, ksc = _combine(ss, cols)
+    c0, b0, k0c = _combine(s0, cols, tb)
+    c1, b1, k1c = _combine(s1, cols, tb)
+    cs, bs, ksc = _combine(ss, cols, tb)
     l0, l1, ls = c0.shape[1], c1.shape[1], cs.shape[1]
 
     # middle = cs - c0 - c1 via per-digit complements (trick 1): the
     # static offsets K0*S(l0), K1*S(l1) join the node constant.
     lm = max(ls, l0, l1)
-    tb = c0.shape[0]
     mid = jnp.zeros((tb, lm), U32)
-    mid = _slice_add(mid, 0, cs)
-    mid = _slice_add(mid, 0, np.uint32(b0) - c0)
-    mid = _slice_add(mid, 0, np.uint32(b1) - c1)
+    mid = add_at(mid, 0, cs)
+    mid = add_at(mid, 0, np.uint32(b0) - c0)
+    mid = add_at(mid, 0, np.uint32(b1) - c1)
     b_mid = bs + b0 + b1
     const_mid = ksc - k0c - k1c + b0 * _ones_value(l0) + b1 * _ones_value(l1)
 
     lout = max(2 * n, k + lm, 2 * k + l1)
     out = jnp.zeros((tb, lout), U32)
-    out = _slice_add(out, 0, c0)
-    out = _slice_add(out, k, mid)
-    out = _slice_add(out, 2 * k, c1)
+    out = add_at(out, 0, c0)
+    out = add_at(out, k, mid)
+    out = add_at(out, 2 * k, c1)
     # frames may overlap by a few pad digits; bound conservatively.
     bound = b_mid + b0 + b1
     assert bound + BASE < 1 << 31, \
@@ -191,14 +193,17 @@ def make_kara_kernel(m: int, threshold: int = DEFAULT_THRESHOLD,
         leaves = []                          # phase A: operand gathering
         spec = _collect(a, b, threshold, leaves)
         nb = max(w for _, _, w in leaves)
-        apad = jnp.stack(
-            [jnp.pad(x, ((0, 0), (0, nb - w))) for x, _, w in leaves], axis=1)
-        bpad = jnp.stack(
-            [jnp.pad(y, ((0, 0), (0, nb - w))) for _, y, w in leaves], axis=1)
+        # leaves stacked along the batch (sublane) axis: a 2-D
+        # (P*TB, nb) block tiles densely, where a (TB, P, nb) one pads P
+        # and nb to the (8, 128) tile
+        apad = jnp.concatenate(
+            [jnp.pad(x, ((0, 0), (0, nb - w))) for x, _, w in leaves], axis=0)
+        bpad = jnp.concatenate(
+            [jnp.pad(y, ((0, 0), (0, nb - w))) for _, y, w in leaves], axis=0)
 
         cols = base_cols(apad, bpad)         # phase B: all base multiplies
 
-        out, bound, const = _combine(spec, cols)   # phase C: lazy recombine
+        out, bound, const = _combine(spec, cols, tb)   # phase C: lazy recombine
         assert bound + BASE < 1 << 31, "lazy columns would overflow uint32"
 
         if const == 0:                       # pure base case (m <= threshold)
@@ -212,14 +217,16 @@ def make_kara_kernel(m: int, threshold: int = DEFAULT_THRESHOLD,
             lp = max(lout, -(-cap.bit_length() // DBITS) + 1)
             d = (1 << (DBITS * lp)) - const
             assert 0 < d, "CONST exceeds the correction headroom"
-            final = jnp.zeros((tb, lp + 1), U32)
-            final = _slice_add(final, 0, out)
-            # per-digit scalar adds (pallas kernels cannot capture
-            # non-scalar constants); zero digits are skipped at trace time
+            # the digits of d as a (1, lp+1) row built from scalar
+            # selects (pallas kernels cannot capture non-scalar
+            # constants); zero digits are skipped at trace time
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, lp + 1), 1)
+            drow = jnp.zeros((1, lp + 1), U32)
             for i in range(lp):
                 di = (d >> (DBITS * i)) & (BASE - 1)
                 if di:
-                    final = final.at[:, i].add(np.uint32(di))
+                    drow = jnp.where(lane == i, np.uint32(di), drow)
+            final = add_at(jnp.zeros((tb, lp + 1), U32), 0, out) + drow
             fbound = bound + BASE
         norm = normalize_static(final, DBITS, bound=fbound)
         out_ref[...] = norm[:, :2 * m]

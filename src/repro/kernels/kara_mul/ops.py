@@ -20,9 +20,10 @@ from repro.resilience import inject as _inject
 U32 = jnp.uint32
 
 
-def _heuristic_tile(m: int, batch: int) -> int:
+def _heuristic_tile(m: int, batch: int,
+                    threshold: int = K.DEFAULT_THRESHOLD) -> int:
     return tiling.batch_tile(
-        m, batch, budget=tiling.budget_words(K.LIVE_U32_ARRAYS),
+        m, batch, budget=tiling.budget_words(K.live_arrays(m, threshold)),
         max_tile=K.MAX_TILE)
 
 
@@ -57,7 +58,7 @@ def kara_mul_digits(a_digits, b_digits, interpret=None,
     batch, m = a.shape
     tb = autotune.pick_tile(
         "kara_mul", (m, batch, 16, threshold, base_mode, interpret),
-        _heuristic_tile(m, batch), batch,
+        _heuristic_tile(m, batch, threshold), batch,
         run=lambda t: _call(a, b, t, threshold, base_mode, interpret),
         max_tile=K.MAX_TILE)
     return _call(a, b, tb, threshold, base_mode, interpret)

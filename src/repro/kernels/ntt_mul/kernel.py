@@ -19,7 +19,8 @@ The DIF/DIT pairing means NO bit-reversal permutation ever materializes
 -- the pointwise product is order-agnostic, so the reversed order lives
 only between the transforms.  Twiddle factors are precomputed on the
 host (ops.py) in Montgomery form and stay VMEM-resident for the whole
-launch; the kernel reads stage s as a static row slice.
+launch; the kernel reads stage s as a static row slice.  Butterfly
+partners meet by lane rotation, not by reshaping into blocks.
 
 Word-size modular arithmetic WITHOUT 64-bit integers: the TPU VPU (and
 uint32-only Pallas) cannot widen a 32x32 product, so modmuls run as
@@ -119,42 +120,56 @@ def sub_mod(a, b, p: int):
 
 # ---------------------------------------------------------------------------
 # Radix-2 stages (static Python loop -- log2(N) stages, every butterfly
-# lane-parallel).  Twiddle rows are Montgomery-domain, one row per stage.
+# lane-parallel).  Each stage works on the whole (TB, N) array: a lane
+# rotation by the half-block size brings every element's butterfly
+# partner into its own lane, and a lane mask picks the top or bottom
+# output -- no reshape, which Mosaic cannot lower for half-blocks
+# narrower than a vreg.  Twiddle rows are Montgomery-domain, one (N,)
+# row per stage (ops.lane_twiddles: row s holds w^(k mod half) at lane k).
 # ---------------------------------------------------------------------------
+
+def _partner(x, half: int, lo):
+    """x[k + half] at lanes with bit ``half`` clear, x[k - half] where set."""
+    n = x.shape[-1]
+    return jnp.where(lo, jnp.roll(x, n - half, 1), jnp.roll(x, half, 1))
+
+
+def _low_half(shape, half: int):
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane & half) == 0
+
 
 def ntt_forward(x, wf, p: int, pinv: int):
     """DIF forward transform, natural order in -> bit-reversed out.
 
-    x: (TB, N); wf: (log2 N, N//2) Montgomery twiddles, stage s using
-    wf[s, :N >> (s+1)].  Butterfly: (u, v) -> (u+v, (u-v) * w^j).
+    x: (TB, N); wf: (log2 N, N) lane twiddles.  Butterfly on the pair
+    (u, v) = (x[j], x[j+half]): (u+v, (u-v) * w^j).
     """
-    tb, n = x.shape
+    n = x.shape[-1]
     for s in range(n.bit_length() - 1):
-        ln = n >> (s + 1)                    # half-block size this stage
-        y = x.reshape(tb, -1, 2, ln)
-        u, v = y[:, :, 0, :], y[:, :, 1, :]
-        w = wf[s, :ln][None, None, :]
-        x = jnp.stack(
-            [add_mod(u, v, p), mont_mul(sub_mod(u, v, p), w, p, pinv)],
-            axis=2).reshape(tb, n)
+        half = n >> (s + 1)
+        lo = _low_half(x.shape, half)
+        y = _partner(x, half, lo)
+        x = jnp.where(lo, add_mod(x, y, p),
+                      mont_mul(sub_mod(y, x, p), wf[s:s + 1, :], p, pinv))
     return x
 
 
 def ntt_inverse(x, wi, p: int, pinv: int, scale: int):
     """DIT inverse transform, bit-reversed in -> natural out.
 
-    Butterfly: (u, v) -> (u + w^-j v, u - w^-j v); the final Montgomery
-    scale constant is N**-1 * R**2 mod p, which both divides by N and
-    cancels the R**-1 the pointwise product introduced.
+    Butterfly on (u, v) = (x[j], x[j+half]): (u + w^-j v, u - w^-j v);
+    the final Montgomery scale constant is N**-1 * R**2 mod p, which
+    both divides by N and cancels the R**-1 the pointwise product
+    introduced.
     """
-    tb, n = x.shape
+    n = x.shape[-1]
     for s in range(n.bit_length() - 1):
-        ln = 1 << s
-        y = x.reshape(tb, -1, 2, ln)
-        u = y[:, :, 0, :]
-        t = mont_mul(y[:, :, 1, :], wi[s, :ln][None, None, :], p, pinv)
-        x = jnp.stack([add_mod(u, t, p), sub_mod(u, t, p)],
-                      axis=2).reshape(tb, n)
+        half = 1 << s
+        lo = _low_half(x.shape, half)
+        t = mont_mul(x, wi[s:s + 1, :], p, pinv)   # w^-j v at the v lanes
+        x = jnp.where(lo, add_mod(x, _partner(t, half, lo), p),
+                      sub_mod(_partner(x, half, lo), t, p))
     return mont_mul(x, jnp.full((), np.uint32(scale), U32), p, pinv)
 
 
@@ -215,8 +230,8 @@ def make_prepared_call(batch_tile: int, n: int, grid: int, p: int,
         in_specs=[
             pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((stages, n // 2), lambda i: (0, 0)),
-            pl.BlockSpec((stages, n // 2), lambda i: (0, 0)),
+            pl.BlockSpec((stages, n), lambda i: (0, 0)),
+            pl.BlockSpec((stages, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((grid * batch_tile, n), U32),
@@ -240,8 +255,8 @@ def make_call(batch_tile: int, n: int, grid: int, p: int, interpret: bool):
         in_specs=[
             pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
             pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
-            pl.BlockSpec((stages, n // 2), lambda i: (0, 0)),
-            pl.BlockSpec((stages, n // 2), lambda i: (0, 0)),
+            pl.BlockSpec((stages, n), lambda i: (0, 0)),
+            pl.BlockSpec((stages, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((grid * batch_tile, n), U32),

@@ -84,14 +84,14 @@ def _resolve_nprimes(ndigits: int, nprimes: int | None) -> int:
 # Host-side twiddle tables (cached per (prime, N); Montgomery domain).
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=64)
 def twiddle_tables(p: int, n: int) -> tuple[np.ndarray, np.ndarray]:
     """(forward, inverse) twiddles, each (log2 N, N//2) uint32, w*R mod p.
 
     Forward stage s (DIF, half-size N >> (s+1)) uses powers of
     w_m = w**(N/m) with m the stage's block size; inverse stage s (DIT,
     half-size 2**s) uses powers of w_m**-1.  Rows are front-filled and
-    zero-padded; the kernel slices the live prefix statically.
+    zero-padded; lane_twiddles spreads them into the layout the kernel
+    reads.
     """
     w = pow(K.GENERATOR, (p - 1) // n, p)
     winv = pow(w, -1, p)
@@ -106,6 +106,21 @@ def twiddle_tables(p: int, n: int) -> tuple[np.ndarray, np.ndarray]:
                 tbl[s, j] = cur * R % p
                 cur = cur * wm % p
     return wf, wi
+
+
+@functools.lru_cache(maxsize=64)
+def lane_twiddles(p: int, n: int) -> tuple[np.ndarray, np.ndarray]:
+    """twiddle_tables spread over the transform's N lanes, the layout the
+    kernel reads: row s holds the stage's twiddle w^(k mod half) at lane
+    k, so a butterfly finds its factor in its own lane.  Cached per
+    (prime, N): the tables are reused by every launch of that width."""
+    lanes = np.arange(n)
+    return tuple(
+        np.stack([tbl[s, lanes % half] for s, half in enumerate(halves)])
+        for tbl, halves in zip(
+            twiddle_tables(p, n),
+            ([n >> (s + 1) for s in range(n.bit_length() - 1)],
+             [1 << s for s in range(n.bit_length() - 1)])))
 
 
 # ---------------------------------------------------------------------------
@@ -320,7 +335,7 @@ def ntt_mul_digits(a_digits, b_digits, nprimes: int | None = None,
     interpret = _auto_interpret(interpret)
     n = next_pow2(2 * nd)
     twiddles = tuple(
-        tuple(jnp.asarray(t) for t in twiddle_tables(p, n))
+        tuple(jnp.asarray(t) for t in lane_twiddles(p, n))
         for p in K.PRIMES[:nprimes])
     tb = autotune.pick_tile(
         "ntt_mul", (n, batch, DIGIT_BITS, nprimes, interpret),
@@ -375,7 +390,7 @@ def ntt_mul_digits_prepared(a_digits, b_value: int,
     interpret = _auto_interpret(interpret)
     n = next_pow2(2 * nd)
     twiddles = tuple(
-        tuple(jnp.asarray(t) for t in twiddle_tables(p, n))
+        tuple(jnp.asarray(t) for t in lane_twiddles(p, n))
         for p in K.PRIMES[:nprimes])
     fb_rows = prepared_operand(b_value, n, nprimes)
     tb = autotune.pick_tile(

@@ -74,8 +74,10 @@ def run(args) -> int:
                          f"choose from {inject.KINDS}")
     n_requests = 40 if args.smoke else args.requests
 
+    # degradation is what the harness exercises: opt in to the kernel
+    # fallback, which is off by default
     api.configure(observability=True, selfcheck="warn",
-                  on_retrace="raise")
+                  on_retrace="raise", kernel_fallback=True)
     _metrics.REGISTRY.reset()
     BREAKER.reset()
     inject.clear()

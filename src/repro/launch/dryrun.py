@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first backend initialization.
 """Multi-pod dry run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this lowers the real step function (train_step with AdamW
@@ -23,6 +19,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -31,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed import sharding as sh
 from repro.launch import hlo_stats
@@ -127,7 +123,7 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, variant: str):
     batch_s = model.input_specs(shape)
     b_shard = sh.to_shardings(sh.batch_pspecs(batch_s, mesh), mesh)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_s = jax.eval_shape(optimizer.init, params_s)
             o_pspec = {"m": pspecs, "v": pspecs,
@@ -199,7 +195,7 @@ def run_cell(arch, shape_name, mesh_kind, variant, out_dir,
             "alias_bytes": mem.alias_size_in_bytes,
             "code_bytes": mem.generated_code_size_in_bytes,
         }
-        cost = compat.cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         rec["cost"] = {"flops": cost.get("flops", 0.0),
                        "bytes_accessed": cost.get("bytes accessed", 0.0)}
         txt = compiled.as_text()
@@ -245,6 +241,10 @@ def enumerate_cells(mesh_kinds=("single", "multi"), variants_on="single"):
 
 
 def main():
+    # 512 fake host devices for the production meshes.  Set here, not at
+    # import, so importing this module never changes the process's
+    # devices; it must run before jax initializes its first backend.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

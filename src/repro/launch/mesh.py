@@ -10,18 +10,26 @@ the multi-pod configuration adds a leading "pod" axis over DCN.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """jax.make_mesh with Auto axes: the model code places activations
+    with with_sharding_constraint, which asserts instead of resharding
+    on the Explicit axes jax.make_mesh gives by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly fake) local devices exist;
     used by subprocess-based distribution tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants for the roofline (TPU v5e per chip)

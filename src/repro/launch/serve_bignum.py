@@ -14,6 +14,7 @@ import random
 
 from repro import api
 from repro.configs.dot_bignum import SERVE, ServeConfig
+from repro.kernels.common.runtime import use_compile_cache
 from repro.serve.bignum_engine import (
     OPS, BignumEngine, NaiveServer, poisson_trace, replay_naive,
     replay_trace)
@@ -62,6 +63,7 @@ def main(argv=None):
                     help="also replay the one-at-a-time baseline")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     templates, warm = build_ops(args.op, args.bits, args.groups, args.seed)
     trace = poisson_trace(templates, args.requests, args.rate,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import sharding as sh
+from repro.launch.mesh import auto_mesh
 from repro.models import build_model
 from repro.train import checkpoint as CKPT
 from repro.train import fault_tolerance as FT
@@ -57,7 +58,7 @@ def main(argv=None):
 
     n_dev = len(jax.devices())
     data_ax = args.data_axis or max(1, n_dev // args.model_axis)
-    mesh = jax.make_mesh((data_ax, args.model_axis), ("data", "model"))
+    mesh = auto_mesh((data_ax, args.model_axis), ("data", "model"))
     multi_device = n_dev > 1
     if multi_device:
         sh.enable_fsdp(mesh)
@@ -103,7 +104,7 @@ def main(argv=None):
         step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
 
     t_start = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             monitor.start()
             batch = jax.tree.map(jnp.asarray, data.batch(step))

@@ -16,11 +16,13 @@ the first success:
     the breaker and its exceptions propagate (there is nothing left to
     fall back to).
 
-``repro.api.configure(kernel_fallback=False)`` turns fall-through off
-(strict mode: the first failure propagates -- CI uses it to catch
-regressions that silent degradation would hide); quarantine skipping
-still applies, because a forced-open breaker is an explicit operator
-decision.
+Fall-through is OFF by default (strict mode: the first failure
+propagates), so a kernel that fails to lower on the device is an error
+and never a silent run of the jnp composition.  A caller that prefers
+an answer at any cost opts in with
+``repro.api.configure(kernel_fallback=True)``; quarantine skipping
+applies either way, because a forced-open breaker is an explicit
+operator decision.
 
 The guard runs at trace time inside jit (core dispatchers call it while
 XLA is tracing), which is exactly where Pallas compile and lowering
@@ -44,10 +46,10 @@ _HELP = "kernel-tier fallbacks by op/backend/reason"
 
 
 def fallback_enabled() -> bool:
-    """configure(kernel_fallback=...): None/True -> degrade through the
-    tiers; False -> strict mode (first failure propagates)."""
-    value = _config.get_override("kernel_fallback")
-    return True if value is None else bool(value)
+    """configure(kernel_fallback=...): True -> degrade through the
+    tiers; None/False (the default) -> strict mode (first failure
+    propagates)."""
+    return bool(_config.get_override("kernel_fallback"))
 
 
 def classify(exc: BaseException) -> str:

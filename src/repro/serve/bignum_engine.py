@@ -44,7 +44,9 @@ The engine assumes failures and bounds them instead of crashing:
   completes later than that ticks ``deadline_miss_total{op,bits}``.
 * **Retry + degrade** -- a flush that raises is retried up to
   ``max_retries`` (exponential backoff from ``retry_backoff_s``); when
-  retries exhaust, the bucket is DEGRADED one backend tier
+  retries exhaust and ``configure(kernel_fallback=True)`` is on (it is
+  off by default, and the error then propagates), the bucket is
+  DEGRADED one backend tier
   (auto/pallas -> jnp -> host reference) and re-run, ticking
   ``fallback_total{op,backend,reason=flush_*}``.  The recompile a
   degrade forces is expected, so it does not trip the retrace alarm.
@@ -273,10 +275,12 @@ class BignumEngine:
     def _degrade(self, bkey: BucketKey, exc: BaseException,
                  phase: str) -> bool:
         """Demote the bucket one tier after ``exc``; False when there is
-        no tier left.  Drops the bucket's compiled program so the next
-        run retraces at the demoted backend (an EXPECTED trace)."""
+        no tier left or ``configure(kernel_fallback=...)`` is off (the
+        default: the failure then propagates).  Drops the bucket's
+        compiled program so the next run retraces at the demoted
+        backend (an EXPECTED trace)."""
         nxt = self._next_tier(bkey)
-        if nxt is None:
+        if nxt is None or not _guard.fallback_enabled():
             return False
         _guard.tick(bkey[0], self._tier_name(bkey),
                     f"{phase}_{_guard.classify(exc)}")
@@ -345,8 +349,9 @@ class BignumEngine:
         -- see ``_on_trace``).
 
         Idempotent per bucket (re-warming a warmed key is a no-op, not a
-        fresh trace) and degraded-not-fatal: a bucket whose warm-up
-        raises is demoted a backend tier and re-warmed; warm only raises
+        fresh trace).  A bucket whose warm-up raises propagates the
+        error, unless ``configure(kernel_fallback=True)`` is on: it is
+        then demoted a backend tier and re-warmed, and warm only raises
         when even the host-reference floor fails."""
         if self._closed:
             raise RuntimeError(

@@ -32,7 +32,6 @@ def test_exact_psum_topology_invariance():
     run_child("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core import exact_accum as EA
 from repro.distributed.collectives import exact_psum_tree
 
@@ -51,8 +50,8 @@ for shape, axes in [((8,), ("data",)), ((4, 2), ("data", "model")),
         tot = jax.lax.psum(acc, "data")
         return EA.decode(EA.normalize(tot))
 
-    fm = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
-    with mesh:
+    fm = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+    with jax.set_mesh(mesh):
         outs[shape] = np.asarray(fm(jnp.asarray(x)))
 # 8-way, 4-way, 2-way reductions of the same data: bitwise identical
 ref = outs[(8,)]
@@ -69,7 +68,6 @@ def test_int8_ef_psum():
     run_child("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed.collectives import int8_ef_psum
 
 mesh = jax.make_mesh((8,), ("data",))
@@ -79,17 +77,17 @@ def f(xl, ef):
     m, ef = int8_ef_psum(xl[0], ef[0], "data", 8)
     return m[None], ef[None]
 
-fm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+fm = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
                out_specs=(P("data"), P("data")))
 ef = jnp.zeros((8, 128), jnp.float32)
-with mesh:
+with jax.set_mesh(mesh):
     mean, ef = fm(jnp.asarray(x), ef)
 mean = np.asarray(mean)[0]
 want = x.mean(0)
 err1 = np.abs(mean - want).max()
 assert err1 < np.abs(x).max() / 127 * 1.01 + 1e-6, err1
 # error feedback: repeating the SAME gradient converges toward exact mean
-with mesh:
+with jax.set_mesh(mesh):
     for _ in range(8):
         mean, ef = fm(jnp.asarray(x), ef)
 # time-average of compressed means approaches the true mean; single-shot
@@ -103,7 +101,6 @@ def test_psum_matmul_ring():
     run_child("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.distributed.collectives import psum_matmul_ring
 
 mesh = jax.make_mesh((8,), ("model",))
@@ -114,9 +111,9 @@ w = rng.standard_normal((64, 32)).astype(np.float32)
 def f(xl, wl):
     return psum_matmul_ring(xl, wl, "model", 8)
 
-fm = shard_map(f, mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
+fm = jax.shard_map(f, mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
                out_specs=P(), check_vma=False)
-with mesh:
+with jax.set_mesh(mesh):
     out = np.asarray(fm(jnp.asarray(x), jnp.asarray(w)))
 np.testing.assert_allclose(out, x @ w, rtol=2e-4, atol=2e-4)
 print("OK")
@@ -178,8 +175,9 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.distributed import sharding as sh
 from repro.train import optimizer
+from repro.launch.mesh import auto_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 sh.enable_fsdp(mesh)
 cfg = get_config("smollm_135m", reduced=True)
 model = build_model(cfg)
@@ -197,11 +195,10 @@ def train_step(params, opt, batch):
     (loss, metrics), grads = jax.value_and_grad(model.loss, has_aux=True)(params, batch)
     return optimizer.update(ocfg, grads, opt, params)
 
-with mesh:
+with jax.set_mesh(mesh):
     co = jax.jit(train_step, in_shardings=(p_shard, o_shard, b_shard),
                  donate_argnums=(0, 1)).lower(params_s, opt_s, batch_s).compile()
-from repro.compat import cost_analysis_dict
-c = cost_analysis_dict(co)
+c = co.cost_analysis()
 assert c["flops"] > 0
 print("OK", c["flops"])
 """)

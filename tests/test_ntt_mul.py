@@ -98,7 +98,7 @@ def test_forward_dif_matches_dft_ref(p):
     n = 32
     pinv = (-pow(p, -1, R)) % R
     x = RNG.integers(0, p, n, dtype=np.int64).astype(np.uint32)
-    wf, _ = NO.twiddle_tables(p, n)
+    wf, _ = NO.lane_twiddles(p, n)
     got = np.asarray(NK.ntt_forward(jnp.asarray(x)[None, :],
                                     jnp.asarray(wf), p, pinv))[0]
     np.testing.assert_array_equal(got, NREF.ntt_fwd_ref(x, p))
@@ -113,7 +113,7 @@ def test_forward_inverse_roundtrip():
     n = 128
     pinv = (-pow(p, -1, R)) % R
     x = RNG.integers(0, p, (4, n), dtype=np.int64).astype(np.uint32)
-    wf, wi = (jnp.asarray(t) for t in NO.twiddle_tables(p, n))
+    wf, wi = (jnp.asarray(t) for t in NO.lane_twiddles(p, n))
     f = NK.ntt_forward(jnp.asarray(x), wf, p, pinv)
     back = np.asarray(NK.ntt_inverse(f, wi, p, pinv,
                                      pow(n, -1, p) * R % p))

@@ -15,6 +15,8 @@ from repro.resilience.breaker import BREAKER, CircuitBreaker, shape_bucket
 
 @pytest.fixture(autouse=True)
 def _clean():
+    # these tests exercise degradation, which is opt-in
+    config.set_overrides({"kernel_fallback": True})
     inject.clear()
     BREAKER.reset()
     yield
@@ -169,6 +171,17 @@ def test_guard_strict_mode():
     config.set_overrides({"kernel_fallback": None})
 
 
+def test_kernel_fallback_strict_by_default():
+    def bad():
+        raise RuntimeError("boom")
+
+    config.set_overrides({"kernel_fallback": None})
+    assert not guard.fallback_enabled()
+    with pytest.raises(RuntimeError, match="boom"):
+        guard.run("t_default", 256, [("pallas", bad), ("jnp", lambda: 1)])
+    assert _fallback_count(op="t_default", reason="RuntimeError") == 1
+
+
 def test_guard_injected_fault_classified():
     inject.install("compile_fail", "t_inj/pallas")
     out = guard.run("t_inj", 512, [("pallas", lambda: 0),
@@ -265,7 +278,7 @@ def test_configure_selfcheck_and_kernel_fallback():
         assert selfcheck.policy() == "warn"
         assert not guard.fallback_enabled()
     assert selfcheck.policy() is None
-    assert guard.fallback_enabled()
+    assert guard.fallback_enabled()          # the fixture's opt-in is back
     with pytest.raises(ValueError, match="selfcheck"):
         api.configure(selfcheck="explode")
     with pytest.raises(ValueError, match="kernel_fallback"):

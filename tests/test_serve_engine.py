@@ -217,12 +217,14 @@ def _resilience_clean():
     from repro import config
     from repro.resilience import inject
     from repro.resilience.breaker import BREAKER
+    # the fault tests exercise degradation, which is opt-in
+    config.set_overrides({"kernel_fallback": True})
     inject.clear()
     BREAKER.reset()
     yield
     inject.clear()
     BREAKER.reset()
-    config.set_overrides({"selfcheck": None})
+    config.set_overrides({"selfcheck": None, "kernel_fallback": None})
 
 
 def test_warm_is_idempotent_per_bucket():
@@ -357,6 +359,25 @@ def test_warm_partial_failure_degrades_not_fatal():
     eng.submit(req, now=0.0)
     done = eng.drain_one()
     assert int(api.from_limbs(done[0].result)) == _oracle(req)
+
+
+def test_strict_default_warm_and_flush_raise():
+    from repro import config
+    config.set_overrides({"kernel_fallback": None})   # the default: strict
+    eng = BE.BignumEngine(SMALL, backend="jnp")
+    n = _odd(80)
+
+    def broken(bkey, reqs):
+        raise RuntimeError("kernel failed to lower")
+
+    eng._execute = broken
+    with pytest.raises(RuntimeError, match="lower"):
+        eng.warm("mod_exp", modulus=n, exponent=0x10001)
+    eng.submit(_mod_exp_req(0, n, e=0x10001), now=0.0)
+    with pytest.raises(RuntimeError, match="lower"):
+        eng.drain_one()
+    assert eng.stats.degraded == 0 and not eng._degraded
+    assert eng.pending() == 1                     # the batch is kept
 
 
 def test_deadline_miss_counter():
