@@ -71,13 +71,14 @@ def split_digits(limbs: jax.Array, to_bits: int) -> jax.Array:
     src2 = np.minimum(src + 1, m_from - 1)
     sh2 = np.where(off > 0, 32 - off, 0).astype(np.uint32)
 
-    limbs = jnp.asarray(limbs, U32)
-    v1 = limbs[..., src] >> jnp.asarray(off, U32)
-    v2 = jnp.where(jnp.asarray(need2),
-                   limbs[..., src2] << jnp.asarray(sh2, U32),
-                   jnp.uint32(0))
-    mask = jnp.uint32((1 << to_bits) - 1)
-    return (v1 | v2) & mask
+    with jax.named_scope("radix_split"):
+        limbs = jnp.asarray(limbs, U32)
+        v1 = limbs[..., src] >> jnp.asarray(off, U32)
+        v2 = jnp.where(jnp.asarray(need2),
+                       limbs[..., src2] << jnp.asarray(sh2, U32),
+                       jnp.uint32(0))
+        mask = jnp.uint32((1 << to_bits) - 1)
+        return (v1 | v2) & mask
 
 
 def join_digits(digits: jax.Array, from_bits: int, m_out: int) -> jax.Array:
@@ -88,25 +89,26 @@ def join_digits(digits: jax.Array, from_bits: int, m_out: int) -> jax.Array:
     ceil(32/from_bits)+1 overlapping digits).
     """
     assert 1 <= from_bits <= 32
-    n = digits.shape[-1]
-    digits = jnp.asarray(digits, U32)
-    i = np.arange(m_out)
-    max_slots = -(-32 // from_bits) + 1
-    acc = jnp.zeros(digits.shape[:-1] + (m_out,), U32)
-    for k in range(max_slots):
-        d = 32 * i // from_bits + k          # digit feeding limb i, slot k
-        sh = from_bits * d - 32 * i          # digit d's bit offset in limb i
-        valid = (d < n) & (sh < 32)          # sh >= -from_bits always
-        d_c = np.minimum(d, n - 1)
-        vals = digits[..., d_c]
-        left = np.clip(sh, 0, 31).astype(np.uint32)
-        right = np.clip(-sh, 0, 31).astype(np.uint32)
-        contrib = jnp.where(jnp.asarray(sh >= 0),
-                            vals << jnp.asarray(left, U32),
-                            vals >> jnp.asarray(right, U32))
-        contrib = jnp.where(jnp.asarray(valid), contrib, jnp.uint32(0))
-        acc = acc | contrib
-    return acc
+    with jax.named_scope("radix_join"):
+        n = digits.shape[-1]
+        digits = jnp.asarray(digits, U32)
+        i = np.arange(m_out)
+        max_slots = -(-32 // from_bits) + 1
+        acc = jnp.zeros(digits.shape[:-1] + (m_out,), U32)
+        for k in range(max_slots):
+            d = 32 * i // from_bits + k        # digit feeding limb i, slot k
+            sh = from_bits * d - 32 * i        # digit d's bit offset in limb i
+            valid = (d < n) & (sh < 32)        # sh >= -from_bits always
+            d_c = np.minimum(d, n - 1)
+            vals = digits[..., d_c]
+            left = np.clip(sh, 0, 31).astype(np.uint32)
+            right = np.clip(-sh, 0, 31).astype(np.uint32)
+            contrib = jnp.where(jnp.asarray(sh >= 0),
+                                vals << jnp.asarray(left, U32),
+                                vals >> jnp.asarray(right, U32))
+            contrib = jnp.where(jnp.asarray(valid), contrib, jnp.uint32(0))
+            acc = acc | contrib
+        return acc
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,7 @@ def make_call(kernel, batch_tile: int, m: int, grid: int,
               interpret: bool):
     return pl.pallas_call(
         kernel,
+        name="dot_add",
         grid=(grid,),
         in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
                   pl.BlockSpec((batch_tile, m), lambda i: (i, 0))],

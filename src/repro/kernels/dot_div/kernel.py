@@ -145,6 +145,7 @@ def make_call(batch_tile: int, wa: int, nb: int, grid: int, interpret: bool):
     """
     return pl.pallas_call(
         make_div_kernel(wa, nb),
+        name="dot_div",
         grid=(grid,),
         in_specs=[pl.BlockSpec((batch_tile, wa), lambda i: (i, 0)),
                   pl.BlockSpec((batch_tile, nb), lambda i: (i, 0))],

@@ -44,6 +44,7 @@ def mul_kernel(a_ref, b_ref, p_ref):
 def make_call(batch_tile: int, m: int, grid: int, interpret: bool):
     return pl.pallas_call(
         mul_kernel,
+        name="dot_mul",
         grid=(grid,),
         in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
                   pl.BlockSpec((batch_tile, m), lambda i: (i, 0))],

@@ -111,6 +111,7 @@ def finalize_kernel(acc_ref, y_ref, *, cfg: ExactAccumConfig):
 def make_encode(cfg, tb, n, grid, interpret):
     return pl.pallas_call(
         functools.partial(encode_kernel, cfg=cfg),
+        name="exact_accum_encode",
         grid=(grid,),
         in_specs=[pl.BlockSpec((tb, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((cfg.num_limbs, tb, n), lambda i: (0, i, 0)),
@@ -123,6 +124,7 @@ def make_accum(L, tb, n, grid, interpret):
     spec = pl.BlockSpec((L, tb, n), lambda i: (0, i, 0))
     return pl.pallas_call(
         accum_kernel,
+        name="exact_accum_add",
         grid=(grid,),
         in_specs=[spec, spec],
         out_specs=spec,
@@ -135,6 +137,7 @@ def make_accum(L, tb, n, grid, interpret):
 def make_finalize(cfg, tb, n, grid, interpret):
     return pl.pallas_call(
         functools.partial(finalize_kernel, cfg=cfg),
+        name="exact_accum_finalize",
         grid=(grid,),
         in_specs=[pl.BlockSpec((cfg.num_limbs, tb, n), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((tb, n), lambda i: (i, 0)),

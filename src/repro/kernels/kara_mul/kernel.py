@@ -239,6 +239,9 @@ def make_call(batch_tile: int, m: int, grid: int, threshold: int,
               base_mode: str, interpret: bool):
     return pl.pallas_call(
         make_kara_kernel(m, threshold, base_mode),
+        # no name=: it would rename the HLO instruction (_call) that the
+        # benchmark's kernel metric keys on; kernel_name stays the
+        # function's name, kara_kernel
         grid=(grid,),
         in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
                   pl.BlockSpec((batch_tile, m), lambda i: (i, 0))],

@@ -63,6 +63,7 @@ def make_mxu_kernel(m: int):
 def make_call(batch_tile: int, m: int, grid: int, interpret: bool):
     return pl.pallas_call(
         make_mxu_kernel(m),
+        name="mxu_mul",
         grid=(grid,),
         in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
                   pl.BlockSpec((batch_tile, m), lambda i: (i, 0))],

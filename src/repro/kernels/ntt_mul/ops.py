@@ -250,8 +250,21 @@ def crt_combine(residues, out_digits: int):
 
     Garner: v = r1 + p1*t2 (+ p1*p2*t3), every multiply against the
     host-known constant digits of p1 / p1*p2 as 16-bit half products,
-    accumulated lazily and resolved with ONE static carry pass.
+    accumulated lazily (``_garner_columns``) and resolved with ONE static
+    carry pass.  On a device trace the two show as the named scopes
+    ``crt_combine/garner`` and ``crt_combine/carry_resolve``.
     """
+    with jax.named_scope("crt_combine"):
+        with jax.named_scope("garner"):
+            cols = _garner_columns(residues, out_digits)
+        with jax.named_scope("carry_resolve"):
+            norm = normalize_static(cols, DIGIT_BITS,
+                                    bound=CRT_COLUMN_TERMS << DIGIT_BITS)
+        return norm[..., :out_digits]
+
+
+def _garner_columns(residues, out_digits: int):
+    """The lazy (unresolved) column sums of the Garner recombination."""
     nprimes = len(residues)
     c = _garner_constants(nprimes)
     p2 = K.PRIMES[1]
@@ -291,10 +304,7 @@ def crt_combine(residues, out_digits: int):
             K.sub_mod(residues[2][..., :out_digits], c12, p3),
             jnp.full((), np.uint32(c["inv12_mont3"]), U32), p3, c["pinv3"])
         cols = acc_prod(cols, t3, c["q_digits"])
-
-    norm = normalize_static(cols, DIGIT_BITS,
-                            bound=CRT_COLUMN_TERMS << DIGIT_BITS)
-    return norm[..., :out_digits]
+    return cols
 
 
 # ---------------------------------------------------------------------------

@@ -9,17 +9,19 @@ Four pillars, one switch:
   * ``repro.obs.metrics`` -- process-level counters / gauges /
     histograms with labels (``REGISTRY``), absorbing the serving
     engine's stats and feeding ``repro.api.metrics()``;
-  * ``repro.obs.spans``   -- wall-time spans split into "trace"
-    (tracing/compile) vs "execute" categories, exportable as
-    Chrome-trace JSON;
+  * ``repro.obs.spans``   -- nested wall-time spans, always annotated
+    on the profiler's timeline, buffered and exportable as Chrome-trace
+    JSON;
   * ``repro.obs.retrace`` -- the zero-retrace contract as a runtime
     guard (``configure(on_retrace="warn"|"raise"|"ignore")``).
 
-Everything is near-zero-cost when off (the default): emit/record are
-guarded no-ops, no events or spans are allocated.  Enable with
+The switch is off by default: dispatch events, registry metrics and the
+span buffer are then guarded no-ops that allocate nothing.  Enable with
 ``repro.api.configure(observability=True)`` (scoped via its context-
 manager form) or the ``enable()`` / ``disable()`` shorthands here.
-The retrace counter is the one exception -- it always ticks, because a
+Two things ignore the switch.  A span's profiler annotation is always
+made (the profiler records it only while a trace is being taken), so
+a device trace shows the program's spans without reconfiguring it.  The retrace counter always ticks, because a
 post-warm retrace is an operational bug worth counting even when
 nobody asked for tracing.
 

@@ -1,23 +1,26 @@
-"""Span profiling: wall-time spans distinguishing trace/compile from
-execute, exportable as Chrome-trace JSON.
+"""Spans: named, nested wall-time intervals on the profiler's timeline,
+and an in-memory buffer of them exportable as Chrome-trace JSON.
 
-The retrace economics that motivate the serving engine (a fresh XLA
-trace costs seconds, the op milliseconds) are invisible in aggregate
-timings; spans make them first-class: callers wrap work in
-``span(name, cat=...)`` (or record measured intervals via ``record``)
-with ``cat`` one of ``CATEGORIES`` -- "trace" for tracing/compile
-work, "execute" for steady-state device work -- and the buffer exports
-to the ``chrome://tracing`` / Perfetto JSON array format, where the
-two categories color differently.
+``span(name, cat=..., **args)`` wraps its body in
+``jax.profiler.TraceAnnotation(name, **args)`` whenever jax is already
+imported.  The profiler records the annotation only while a trace is
+being taken, so it is always on: a device trace taken around the
+program shows every span, nested, on the host timeline beside the
+device's ops.  The body may add args as it
+learns them (``with span(...) as args: args["compiled"] = True``); they
+reach the annotation and the buffer at exit.
 
-When jax is already loaded, an enabled ``span`` also wraps the body in
-``jax.profiler.TraceAnnotation`` so the same names show up inside a
-jax device profile; nothing here imports jax otherwise (the obs
-package stays stdlib-only).
+Only while observability is on (``repro.api.configure(observability=
+True)``) does a span also go into the buffer.  ``cat`` is one of
+``CATEGORIES``: "trace" for tracing/compile work, "execute" for
+steady-state work; a span whose args say ``compiled=True`` is filed
+under "trace" whatever its ``cat``.  The buffer exports to the
+``chrome://tracing`` / Perfetto JSON format, where the two categories
+sit on separate rows.  Its timestamps are ``time.time_ns``, the
+profiler's own clock, in microseconds, so exported spans line up with a
+device trace taken at the same time.
 
-Recording is a no-op when observability is off; timestamps are
-``time.perf_counter`` relative to process start of this module, in
-microseconds (what the trace viewer expects).
+Nothing here imports jax (the obs package stays stdlib-only).
 """
 from __future__ import annotations
 
@@ -25,13 +28,12 @@ import contextlib
 import json
 import sys
 import time
-from typing import List, Optional
+from typing import List
 
 from repro import config as _config
 
 CATEGORIES = ("trace", "execute")
 
-_T0 = time.perf_counter()
 _spans: List[dict] = []
 _MAX_SPANS = 65536                  # hard cap: drop, never grow unbounded
 
@@ -40,46 +42,40 @@ def enabled() -> bool:
     return bool(_config.get_override("observability"))
 
 
-def record(name: str, cat: str, t0: float, dur_s: float, **args) -> None:
-    """Record one measured interval (``t0`` from time.perf_counter).
-
-    The low-level hook for callers that only know the category AFTER
-    the work ran (the serving engine categorizes a flush as "trace"
-    iff the jit cache missed)."""
-    if not _config.get_override("observability"):
-        return
-    if cat not in CATEGORIES:
-        raise ValueError(f"unknown span category {cat!r}; choose from "
-                         f"{CATEGORIES}")
-    if len(_spans) >= _MAX_SPANS:
-        return
-    _spans.append({
-        "name": name, "cat": cat,
-        "ts": (t0 - _T0) * 1e6, "dur": dur_s * 1e6,
-        "args": {k: v for k, v in args.items()},
-    })
+def _annotation(name: str, args: dict):
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 @contextlib.contextmanager
 def span(name: str, cat: str = "execute", **args):
-    """Context manager form of ``record``; annotates via
-    ``jax.profiler.TraceAnnotation`` when jax is already imported."""
-    if not _config.get_override("observability"):
-        yield
-        return
-    ann = contextlib.nullcontext()
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            ann = jax.profiler.TraceAnnotation(name)
-        except Exception:  # noqa: BLE001 - annotation is best-effort
-            pass
-    t0 = time.perf_counter()
+    """Annotate the body on the profiler's timeline and, with
+    observability on, buffer it.  Yields the args dict: entries the body
+    adds are attached at exit."""
+    if cat not in CATEGORIES:
+        raise ValueError(f"unknown span category {cat!r}; choose from "
+                         f"{CATEGORIES}")
+    ann = _annotation(name, args)
+    given = set(args)
+    t0 = time.time_ns()
     try:
-        with ann:
-            yield
+        with ann if ann is not None else contextlib.nullcontext():
+            try:
+                yield args
+            finally:
+                late = {k: v for k, v in args.items() if k not in given}
+                if ann is not None and late:
+                    ann.set_metadata(**late)
     finally:
-        record(name, cat, t0, time.perf_counter() - t0, **args)
+        if enabled() and len(_spans) < _MAX_SPANS:
+            _spans.append({
+                "name": name,
+                "cat": "trace" if args.get("compiled") else cat,
+                "ts": t0 / 1e3, "dur": (time.time_ns() - t0) / 1e3,
+                "args": dict(args),
+            })
 
 
 def spans() -> List[dict]:
@@ -108,9 +104,3 @@ def write_chrome_trace(path: str) -> str:
         json.dump(chrome_trace(), f, indent=1)
         f.write("\n")
     return path
-
-
-def total_seconds(cat: Optional[str] = None) -> float:
-    """Summed span wall time (optionally one category's) in seconds."""
-    return sum(s["dur"] for s in _spans
-               if cat is None or s["cat"] == cat) * 1e-6

@@ -26,7 +26,7 @@ from typing import Callable, List, Optional, Tuple
 
 from repro import config as _config
 
-DEFAULT_CAPACITY = 1024
+CAPACITY = 1024                     # ring buffer: keeps the newest events
 
 DISPATCHERS = ("mul", "div", "modexp", "modexp_window")
 
@@ -45,7 +45,7 @@ class DispatchEvent:
     detail: Tuple[Tuple[str, object], ...] = ()
 
 
-_events: deque = deque(maxlen=DEFAULT_CAPACITY)
+_events: deque = deque(maxlen=CAPACITY)
 _subscribers: List[Callable[[DispatchEvent], None]] = []
 
 
@@ -90,14 +90,6 @@ def events(dispatcher: Optional[str] = None) -> List[DispatchEvent]:
 
 def clear() -> None:
     _events.clear()
-
-
-def set_capacity(n: int) -> None:
-    """Resize the ring buffer (keeps the newest ``n`` events)."""
-    global _events
-    if n < 1:
-        raise ValueError(f"trace capacity must be >= 1, got {n}")
-    _events = deque(_events, maxlen=n)
 
 
 def report(evts: Optional[List[DispatchEvent]] = None) -> List[dict]:

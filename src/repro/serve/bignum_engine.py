@@ -63,6 +63,17 @@ The engine assumes failures and bounds them instead of crashing:
   REPAIRED from the witness before results are returned, ticking
   ``selfcheck_failures_total`` and applying the warn/raise policy.
 
+Spans and counters
+------------------
+Every ``submit`` runs in a ``serve.submit`` span and every flush in a
+``serve.flush`` span (args: a per-engine ``flush`` sequence number,
+``op``, ``bits``, ``lanes``, ``reason``, the served ``rids``, and
+``compiled=True`` when the jit cache missed), which holds
+``serve.assemble`` (the padded batch), ``serve.dispatch`` (the jitted
+call), ``serve.device_wait`` (blocking on the result) and
+``serve.unpack`` (checks and hand-out).  ``EngineStats`` adds up the
+engine's own waits on its own clock: see its docstring.
+
 All arithmetic goes through the ``repro.api`` facade; this module never
 imports the digit-radix internals.
 """
@@ -106,6 +117,7 @@ class BignumRequest:
     sla_s: Optional[float] = None       # per-request latency SLA
     arrival: float = 0.0
     deadline: float = 0.0
+    queued_at: float = 0.0              # engine clock when submit queued it
     completion: Optional[float] = None
     result: Optional[np.ndarray] = None
     shed: bool = False                  # rejected at admission (no result)
@@ -119,6 +131,12 @@ class BignumRequest:
 
 @dataclasses.dataclass
 class EngineStats:
+    """Counters of one engine since it was made.  Every field is a plain
+    number, so the difference of two snapshots, field by field, is what
+    happened between them (no lists, dicts or maxima here).  The
+    ``*_s`` fields are seconds on the engine's own ``time.perf_counter``
+    clock, never the callers' ``now``."""
+
     traces: int = 0           # jit cache misses (python body executions)
     programs: int = 0         # distinct compiled entry points
     served: int = 0
@@ -131,6 +149,9 @@ class EngineStats:
     degraded: int = 0         # bucket backend-tier demotions
     deadline_misses: int = 0  # requests completing past their sla_s
     selfcheck_failures: int = 0   # lanes caught (and repaired) by selfcheck
+    wait_s: float = 0.0           # per served request: flush start - queued
+    flush_s: float = 0.0          # wall time of the flushes that served
+    device_wait_s: float = 0.0    # blocked on device results (warm too)
 
 
 class BignumEngine:
@@ -159,6 +180,7 @@ class BignumEngine:
         self._degraded: Dict[BucketKey, str] = {}   # bucket -> demoted tier
         self._expect_trace = False          # a degrade's recompile is legit
         self._closed = False
+        self._flush_seq = 0                 # serve.flush span ids
 
     # -- bucketing --------------------------------------------------------
 
@@ -240,21 +262,28 @@ class BignumEngine:
         compiled program; returns the (slots, limbs) result block."""
         op, nbits, ebits, _ = bkey
         slots = self.cfg.slots
-        fn = self._fn(bkey, reqs[0])
-        lw = nbits // 32 if op == "mod_exp" else -(-reqs[0].key.bits // 32)
-        base = np.zeros((slots, lw), np.uint32)
-        for i, r in enumerate(reqs):
-            v = np.asarray(r.value, np.uint32).reshape(-1)
-            base[i, : v.size] = v
-        base[len(reqs):] = base[0]              # pad: repeat lane 0
-        if op == "mod_exp":
-            rows = [np.asarray(api.exp_bits_msb(r.exponent, ebits))
-                    for r in reqs]
-            rows += [rows[0]] * (slots - len(reqs))
-            out = fn(base, np.stack(rows))
-        else:
-            out = fn(base)
-        return np.asarray(jax.block_until_ready(out))
+        with obs.span("serve.assemble"):
+            fn = self._fn(bkey, reqs[0])
+            lw = (nbits // 32 if op == "mod_exp"
+                  else -(-reqs[0].key.bits // 32))
+            base = np.zeros((slots, lw), np.uint32)
+            for i, r in enumerate(reqs):
+                v = np.asarray(r.value, np.uint32).reshape(-1)
+                base[i, : v.size] = v
+            base[len(reqs):] = base[0]              # pad: repeat lane 0
+            args = (base,)
+            if op == "mod_exp":
+                rows = [np.asarray(api.exp_bits_msb(r.exponent, ebits))
+                        for r in reqs]
+                rows += [rows[0]] * (slots - len(reqs))
+                args += (np.stack(rows),)
+        with obs.span("serve.dispatch"):
+            out = fn(*args)
+        t0 = time.perf_counter()
+        with obs.span("serve.device_wait"):
+            out = np.asarray(jax.block_until_ready(out))
+        self.stats.device_wait_s += time.perf_counter() - t0
+        return out
 
     # -- degradation ------------------------------------------------------
 
@@ -388,6 +417,11 @@ class BignumEngine:
         SHED -- returned immediately with ``shed=True`` and no result,
         ticking ``shed_total{op}`` -- so overload degrades to bounded,
         observable rejections instead of unbounded queue growth."""
+        with obs.span("serve.submit", rid=req.rid):
+            return self._submit(req, now)
+
+    def _submit(self, req: BignumRequest, now: float
+                ) -> List[BignumRequest]:
         if self._closed:
             raise RuntimeError(
                 "BignumEngine is closed; submit() after close() is "
@@ -405,6 +439,7 @@ class BignumEngine:
                 op=req.op)
             return [req]
         q = self._queues.setdefault(bkey, [])
+        req.queued_at = time.perf_counter()
         q.append(req)
         if len(q) == 1:
             self._deadlines[bkey] = req.deadline
@@ -458,20 +493,39 @@ class BignumEngine:
 
     def _flush(self, bkey: BucketKey, reason: str,
                now: Optional[float] = None) -> List[BignumRequest]:
+        t_start = time.perf_counter()
         reqs = self._queues.pop(bkey)
         deadline = self._deadlines.pop(bkey, None)
         traces0 = self.stats.traces
-        t0 = time.perf_counter()
-        try:
-            out = self._run_batch(bkey, reqs)
-        except Exception:
-            # retries and degradation are exhausted: put the batch back
-            # so close()/drain keep seeing it, then let the error surface
-            self._queues[bkey] = reqs
-            if deadline is not None:
-                self._deadlines[bkey] = deadline
-            raise
-        dt = time.perf_counter() - t0
+        self._flush_seq += 1
+        with obs.span("serve.flush", flush=self._flush_seq, op=bkey[0],
+                      bits=bkey[1], lanes=len(reqs), reason=reason,
+                      rids=" ".join(str(r.rid) for r in reqs)) as args:
+            try:
+                out = self._run_batch(bkey, reqs)
+            except Exception:
+                # retries and degradation are exhausted: put the batch
+                # back so close()/drain keep seeing it, then let the
+                # error surface
+                self._queues[bkey] = reqs
+                if deadline is not None:
+                    self._deadlines[bkey] = deadline
+                raise
+            dt = time.perf_counter() - t_start
+            if self.stats.traces > traces0:
+                args["compiled"] = True
+            with obs.span("serve.unpack"):
+                self._unpack(bkey, reqs, reason, out, now, dt)
+        st = self.stats
+        st.wait_s += sum(t_start - r.queued_at for r in reqs)
+        st.flush_s += time.perf_counter() - t_start
+        return list(reqs)
+
+    def _unpack(self, bkey: BucketKey, reqs: List[BignumRequest],
+                reason: str, out: np.ndarray, now: Optional[float],
+                dt: float) -> None:
+        """Fault injection, self-check and the hand-out of a flushed
+        batch's results; the flush's counters."""
         op = bkey[0]
         # result-trimmed region: mod_exp pads to the bucket width but only
         # the natural modulus width is returned (all requests in a bucket
@@ -506,9 +560,7 @@ class BignumEngine:
                     "served requests whose latency exceeded sla_s").inc(
                     op=op, bits=bkey[1])
         if obs.enabled():
-            self._observe_flush(bkey, reqs, reason, now, t0, dt,
-                                traced=self.stats.traces > traces0)
-        return list(reqs)
+            self._observe_latency(bkey, reqs, now, dt)
 
     def _selfcheck_batch(self, bkey: BucketKey, reqs: List[BignumRequest],
                          out: np.ndarray, trim: int) -> np.ndarray:
@@ -540,40 +592,20 @@ class BignumEngine:
                               bits=nbits)
         return out
 
-    def _observe_flush(self, bkey: BucketKey, reqs: List[BignumRequest],
-                       reason: str, now: Optional[float], t0: float,
-                       dt: float, traced: bool) -> None:
-        """Mirror one flush into the metrics registry + span buffer
-        (only called with observability on).
+    def _observe_latency(self, bkey: BucketKey, reqs: List[BignumRequest],
+                         now: Optional[float], dt: float) -> None:
+        """Observe each request's latency in the
+        ``serve_request_latency_seconds`` histogram (observability on).
 
-        Request latency = virtual queue wait (``now`` - arrival, on the
-        caller's clock) + the REAL measured service time of this flush
-        -- the same accounting replay_trace uses, so the histogram
-        p50/p95/p99 agree with ReplayResult on a replayed trace.  The
-        span category is "trace" iff this flush compiled (the jitted
-        body ran), which is exactly the seconds-vs-milliseconds split
-        the engine exists to manage."""
-        op, nbits, _, _ = bkey
-        r = obs.REGISTRY
-        labels = {"op": op, "bits": nbits}
-        obs.spans.record(f"serve/{op}/{nbits}", "trace" if traced
-                         else "execute", t0, dt,
-                         batch=len(reqs), reason=reason)
-        r.counter("serve_requests_total",
-                  "requests served by the batching engine").inc(
-            len(reqs), **labels)
-        r.counter("serve_batches_total",
-                  "engine flushes by trigger").inc(reason=reason, **labels)
-        r.counter("serve_padded_lanes_total",
-                  "slots padded by repeating lane 0").inc(
-            self.cfg.slots - len(reqs), **labels)
-        hist = r.histogram("serve_request_latency_seconds",
-                           "queue wait + measured service time")
+        Latency = virtual queue wait (``now`` - arrival, on the caller's
+        clock) + the REAL measured service time of this flush -- the same
+        accounting replay_trace uses, so the histogram p50/p95/p99 agree
+        with ReplayResult on a replayed trace."""
+        hist = obs.REGISTRY.histogram("serve_request_latency_seconds",
+                                      "queue wait + measured service time")
         for q in reqs:
             wait = max(0.0, now - q.arrival) if now is not None else 0.0
-            hist.observe(wait + dt, **labels)
-        r.gauge("serve_queue_depth",
-                "requests enqueued across buckets").set(self.pending())
+            hist.observe(wait + dt, op=bkey[0], bits=bkey[1])
 
 
 # ---------------------------------------------------------------------------

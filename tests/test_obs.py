@@ -1,14 +1,21 @@
 """repro.obs: metrics registry math, dispatch tracing from the real
-tier choosers, span profiling / Chrome-trace export, the retrace alarm
-on the serving engine, and the disabled-mode zero-overhead contract.
+tier choosers, span profiling / Chrome-trace export, the engine's spans
+on a profiler trace and its wait/flush/device counters, the retrace
+alarm on the serving engine, and the disabled-mode zero-overhead
+contract.
 
-Everything here is host-side (the dispatchers run without launching a
-kernel; the engine tests stub ``_execute``), so the module adds
-seconds, not minutes, to tier 1."""
+Everything here is host-side or tiny (the dispatchers run without
+launching a kernel; most engine tests stub ``_execute``, the rest serve
+96-bit moduli on the jnp backend), so the module adds seconds, not
+minutes, to tier 1."""
+import dataclasses
 import json
+import pathlib
 import random
+import time
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -175,14 +182,10 @@ def test_trace_subscribe_and_capacity():
         assert len(seen) == 1 and seen[0].dispatcher == "mul"
     finally:
         unsub()
-    obs.trace.set_capacity(2)
-    try:
-        with _observing():
-            for _ in range(5):
-                select_method(64, batch=1)
-        assert len(obs.dispatch_events()) == 2   # ring buffer bounded
-    finally:
-        obs.trace.set_capacity(obs.trace.DEFAULT_CAPACITY)
+    with _observing():
+        for _ in range(obs.trace.CAPACITY + 5):
+            select_method(64, batch=1)
+    assert len(obs.dispatch_events()) == obs.trace.CAPACITY  # ring bounded
 
 
 def test_disabled_mode_no_events_no_metrics():
@@ -209,23 +212,30 @@ def test_span_records_and_chrome_trace(tmp_path):
     with _observing():
         with obs.span("compile", cat="trace", bits=256):
             pass
-        obs.spans.record("exec", "execute", 0.0, 0.25, batch=4)
+        with obs.span("exec", batch=4) as args:
+            time.sleep(0.02)
+        with obs.span("late") as late:
+            late["compiled"] = True          # added by the body: "trace"
         with pytest.raises(ValueError, match="choose from"):
-            obs.spans.record("bad", "nope", 0.0, 1.0)
+            with obs.span("bad", cat="nope"):
+                pass
+    assert args == {"batch": 4}
     spans = obs.spans.spans()
-    assert [s["cat"] for s in spans] == ["trace", "execute"]
-    assert obs.spans.total_seconds("execute") == pytest.approx(0.25)
+    assert [s["cat"] for s in spans] == ["trace", "execute", "trace"]
+    assert spans[2]["args"] == {"compiled": True}
     path = obs.write_chrome_trace(str(tmp_path / "t.json"))
     doc = json.loads(open(path).read())
     assert doc["displayTimeUnit"] == "ms"
     evs = doc["traceEvents"]
-    assert len(evs) == 2
+    assert len(evs) == 3
     for e in evs:
         assert e["ph"] == "X" and {"name", "cat", "ts", "dur",
                                    "pid", "tid"} <= set(e)
     # categories land on distinct tids so the viewer separates them
     assert {e["tid"] for e in evs} == {1, 2}
-    assert evs[1]["dur"] == pytest.approx(0.25e6)    # microseconds
+    assert 0.02e6 <= evs[1]["dur"] < 1e6             # microseconds
+    # timestamps are the profiler's clock (time.time_ns), in us
+    assert abs(evs[2]["ts"] - time.time_ns() / 1e3) < 60e6
 
 
 # ---------------------------------------------------------------------------
@@ -264,14 +274,15 @@ def test_engine_flush_populates_latency_histogram():
     assert h.count(op="mod_exp", bits=96) == SMALL.slots
     # every latency >= its queue wait; oldest request waited longest
     assert h.quantile(1.0, op="mod_exp", bits=96) >= 0.003
-    c = obs.REGISTRY.get("serve_requests_total")
-    assert c.value(op="mod_exp", bits=96) == SMALL.slots
-    assert obs.REGISTRY.get("serve_batches_total").value(
-        op="mod_exp", bits=96, reason="full") == 1
-    assert obs.REGISTRY.get("serve_queue_depth").value() == 0
-    (sp,) = obs.spans.spans()
-    assert sp["name"] == "serve/mod_exp/96"
-    assert sp["args"] == {"batch": SMALL.slots, "reason": "full"}
+    st = eng.stats
+    assert (st.served, st.batches, st.flush_full) == (SMALL.slots, 1, 1)
+    assert eng.pending() == 0
+    names = [sp["name"] for sp in obs.spans.spans()]
+    assert names.count("serve.submit") == SMALL.slots
+    (sp,) = [sp for sp in obs.spans.spans() if sp["name"] == "serve.flush"]
+    assert sp["args"] == {"flush": 1, "op": "mod_exp", "bits": 96,
+                          "lanes": SMALL.slots, "reason": "full",
+                          "rids": "0 1 2 3"}
 
 
 def test_engine_padded_lanes_and_deadline_reason():
@@ -281,10 +292,10 @@ def test_engine_padded_lanes_and_deadline_reason():
         assert eng.submit(_req(0, n), now=0.0) == []
         done = eng.flush_next_due(now=1.0)
     assert len(done) == 1
-    assert obs.REGISTRY.get("serve_padded_lanes_total").value(
-        op="mod_exp", bits=96) == SMALL.slots - 1
-    assert obs.REGISTRY.get("serve_batches_total").value(
-        op="mod_exp", bits=96, reason="deadline") == 1
+    assert eng.stats.padded_lanes == SMALL.slots - 1
+    assert (eng.stats.flush_deadline, eng.stats.flush_full) == (1, 0)
+    (sp,) = [sp for sp in obs.spans.spans() if sp["name"] == "serve.flush"]
+    assert sp["args"]["reason"] == "deadline"
 
 
 def test_engine_disabled_mode_serves_without_metrics():
@@ -345,6 +356,123 @@ def test_multiple_warms_do_not_false_alarm():
         eng.warm("mod_exp", modulus=_odd(90), exponent=65537)
         eng.warm("mod_exp", modulus=_odd(150), exponent=65537)
     assert RT.count("serve") == 0
+
+
+# ---------------------------------------------------------------------------
+# serving engine: spans on a profiler trace, and its own wait counters
+# ---------------------------------------------------------------------------
+
+FLUSH_CHILDREN = ("serve.assemble", "serve.dispatch", "serve.device_wait",
+                  "serve.unpack")
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU profiler trace; its host events by name
+    (absolute ns on the profiler's clock) and the buffered spans."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    (env,) = [p for p in data.planes if p.name == "Task Environment"]
+    t0 = dict(env.stats)["profile_start_time"]
+    events = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(
+                    (t0 + ev.start_ns, t0 + ev.start_ns + ev.duration_ns,
+                     dict(ev.stats)))
+    return events
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_engine_spans_on_profiler_with_observability_off(tmp_path):
+    eng = BE.BignumEngine(SMALL, backend="jnp")
+    n = _odd(90)
+    eng.warm("mod_exp", modulus=n, exponent=65537)
+    assert not obs.enabled()
+    done = []
+    events = _profiled(tmp_path, lambda: [
+        done.extend(eng.submit(_req(i, n), now=0.0))
+        for i in range(SMALL.slots)])
+    assert len(done) == SMALL.slots
+    assert obs.spans.spans() == []               # buffer stays off
+    # the bench. prefix is the chip benchmark's own annotations: its
+    # idle-gap attribution must never read one of the program's spans
+    assert not [n for n in events if n.startswith("bench.")]
+    submits = events["serve.submit"]
+    assert sorted(s[2]["rid"] for s in submits) == list(range(SMALL.slots))
+    (flush,) = events["serve.flush"]
+    assert flush[2]["flush"] == 1 and flush[2]["lanes"] == SMALL.slots
+    assert flush[2]["rids"] == "0 1 2 3"
+    assert "compiled" not in flush[2]            # warmed: a jit cache hit
+    # the full flush runs inside the submit that filled the batch
+    assert _inside(flush, max(submits))
+    spans = [flush]
+    for name in FLUSH_CHILDREN:
+        (child,) = events[name]
+        assert _inside(child, flush), name
+        spans.append(child)
+    # the children follow one another in order
+    for a, b in zip(spans[1:], spans[2:]):
+        assert a[1] <= b[0]
+
+
+def test_buffered_span_starts_with_its_annotation(tmp_path):
+    buffered = []
+
+    def body():
+        with _observing():
+            with obs.span("probe"):
+                time.sleep(0.005)
+        buffered.extend(obs.spans.spans())
+
+    events = _profiled(tmp_path, body)
+    (ann,) = events["probe"]
+    (sp,) = buffered
+    assert abs(sp["ts"] * 1e3 - ann[0]) < 1e6   # ns: within 1 ms
+    assert abs(sp["dur"] * 1e3 - (ann[1] - ann[0])) < 1e6
+
+
+def test_engine_counters_wait_flush_device():
+    eng = BE.BignumEngine(SMALL, backend="jnp")
+    n = _odd(90)
+    eng.warm("mod_exp", modulus=n, exponent=65537)
+    warm = dataclasses.replace(eng.stats)
+    assert warm.flush_s == warm.wait_s == 0.0
+    assert warm.device_wait_s > 0                # the warm-up's run counts
+    for i in range(SMALL.slots):
+        eng.submit(_req(i, n), now=123.0)       # a caller's virtual clock
+    st = eng.stats
+    device_wait_s = st.device_wait_s - warm.device_wait_s
+    assert st.batches == 1
+    assert st.wait_s >= 0 and st.flush_s > 0
+    assert 0 < device_wait_s <= st.flush_s
+    # the engine's own clock: the callers' now never enters the counters
+    assert st.wait_s < 60 and st.flush_s < 60
+    assert all(isinstance(v, (int, float))
+               for v in dataclasses.asdict(st).values())
+
+
+def test_deadline_flush_adds_the_wait_to_wait_s():
+    eng = _stub_engine()
+    n = _odd(90)
+    lanes = 2
+    for i in range(lanes):
+        eng.submit(_req(i, n), now=0.0)
+    time.sleep(SMALL.max_wait_s)
+    done = eng.flush_next_due(now=1.0)
+    assert len(done) == lanes
+    assert eng.stats.wait_s >= SMALL.max_wait_s * lanes
+    assert eng.stats.device_wait_s == 0.0 <= eng.stats.flush_s
 
 
 # ---------------------------------------------------------------------------
