@@ -33,8 +33,12 @@ NORMAL domain throughout: twiddles are stored as w*R mod p, so
 a stray R**-1, cancelled by folding R**2 into the inverse transform's
 1/N scale constant.
 
-CRT recombination of the per-prime residues runs in plain jnp (ops.py)
-and funnels into ONE deferred-carry resolve via common/carry.py.
+CRT recombination of the per-prime residues is a second kernel,
+``crt_combine`` (one launch after the per-prime launches): Garner's
+mixed-radix digits, their 16-bit half products against the host-known
+digits of p1 (and p1*p2) placed into lazy columns, and ONE
+deferred-carry resolve via common/carry.py -- a single VMEM pass per
+batch tile from residues to normalized digits.
 """
 from __future__ import annotations
 
@@ -44,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels.common.carry import add_at, normalize_static
 
 U32 = jnp.uint32
 R_BITS = 32                      # Montgomery radix R = 2**32
@@ -63,6 +69,20 @@ GENERATOR = 3
 # Montgomery multiply (those are (TB, N/2)-sized; counted as halves).
 LIVE_U32_ARRAYS = 16
 MAX_TILE = 128
+
+DIGIT_BITS = 16
+DMASK = np.uint32(0xFFFF)
+
+# Worst-case lazy terms landing on one CRT output column (2 from r1's
+# lo/hi, 8 from t2 x p1's 2x2 half products, 16 from t3 x (p1*p2)'s 2x4),
+# each < 2**16: the bound fed to the single normalize_static resolve.
+CRT_COLUMN_TERMS = 26
+
+# Live (TB, out_digits) uint32 arrays in the crt_combine body at its peak,
+# counted as LIVE_U32_ARRAYS is: during t3's Montgomery multiply, r1, t2,
+# r3 and c12 beside its ~8 half-product temps.  The per-offset column
+# sums and the carry network come later, when those are dead.
+CRT_LIVE_U32_ARRAYS = 12
 
 
 # ---------------------------------------------------------------------------
@@ -264,5 +284,115 @@ def make_call(batch_tile: int, n: int, grid: int, p: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((batch_tile, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((grid * batch_tile, n), U32),
+        interpret=interpret,
+    )
+
+
+# ---------------------------------------------------------------------------
+# CRT recombination: Garner + one carry resolve, one VMEM pass per tile.
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=8)
+def garner_constants(nprimes: int) -> dict:
+    """Host-precomputed Montgomery constants for Garner recombination."""
+    p1, p2 = PRIMES[0], PRIMES[1]
+    r = 1 << R_BITS
+    c = {
+        "pinv2": (-pow(p2, -1, r)) % r,
+        "inv1_mont2": pow(p1, -1, p2) * r % p2,     # mont_mul -> * p1^-1
+        "p1_digits": tuple((p1 >> (16 * k)) & 0xFFFF for k in range(2)),
+    }
+    if nprimes >= 3:
+        p3 = PRIMES[2]
+        q = p1 * p2
+        c.update({
+            "pinv3": (-pow(p3, -1, r)) % r,
+            "p1_mont3": p1 * r % p3,                # mont_mul -> * p1
+            "inv12_mont3": pow(q, -1, p3) * r % p3,  # mont_mul -> * q^-1
+            "q_digits": tuple((q >> (16 * k)) & 0xFFFF for k in range(4)),
+        })
+    return c
+
+
+def _const(v: int):
+    return jnp.full((), np.uint32(v), U32)
+
+
+def make_crt_combine_kernel(nprimes: int, out_digits: int):
+    """Body: per-prime residue tiles -> normalized radix-2**16 digits.
+
+    Garner with ascending primes needs no residue pre-reduction
+    (r1 < p1 < p2, t2 < p2 < p3):  v = r1 + p1*t2 (+ p1*p2*t3).  Each
+    mixed-radix digit x < 2**30 times a host constant C splits into
+    16-bit half products (x_lo, x_hi) x C's 16-bit digits, each exact in
+    uint32; their lo/hi halves are summed per column offset (no lane
+    movement), each offset's sum is placed once with ``add_at``, and the
+    columns (< CRT_COLUMN_TERMS terms of < 2**16) take ONE
+    ``normalize_static`` resolve.
+    """
+    c = garner_constants(nprimes)
+    p2 = PRIMES[1]
+
+    def crt_combine_kernel(*refs):
+        *res_refs, out_ref = refs
+        res = [ref[:, :out_digits] for ref in res_refs]
+        r1 = res[0]
+        t2 = mont_mul(sub_mod(res[1], r1, p2), _const(c["inv1_mont2"]),
+                      p2, c["pinv2"])
+        parts = [(r1, (1,)), (t2, c["p1_digits"])]
+        if nprimes >= 3:
+            p3 = PRIMES[2]
+            c12 = add_mod(r1, mont_mul(t2, _const(c["p1_mont3"]), p3,
+                                       c["pinv3"]), p3)
+            t3 = mont_mul(sub_mod(res[2], c12, p3),
+                          _const(c["inv12_mont3"]), p3, c["pinv3"])
+            parts.append((t3, c["q_digits"]))
+
+        sums = {}                        # column offset -> lazy term sum
+
+        def put(off, v):
+            sums[off] = sums[off] + v if off in sums else v
+
+        for x, digits in parts:
+            for o, half in enumerate((x & DMASK, x >> np.uint32(16))):
+                for k, ck in enumerate(digits):
+                    if ck == 1:          # the product is the half itself
+                        put(k + o, half)
+                    elif ck:
+                        prod = half * np.uint32(ck)      # exact in uint32
+                        put(k + o, prod & DMASK)
+                        put(k + o + 1, prod >> np.uint32(16))
+
+        cols = sums[0]
+        for off in sorted(sums)[1:]:
+            # Columns at or above out_digits are cut here: the digits are
+            # the value mod 2**(16 * out_digits), and a carry only moves
+            # up, so those columns cannot reach the digits kept.
+            if off < out_digits:
+                cols = add_at(cols, off, sums[off][:, :out_digits - off])
+        out_ref[...] = normalize_static(
+            cols, DIGIT_BITS, bound=CRT_COLUMN_TERMS << DIGIT_BITS)
+
+    return crt_combine_kernel
+
+
+@functools.lru_cache(maxsize=64)
+def make_crt_call(batch_tile: int, rows: int, n: int, out_digits: int,
+                  nprimes: int, interpret: bool):
+    """pallas_call for the CRT recombination: ``nprimes`` (rows, n)
+    residue arrays -> (rows, out_digits) digits.  A residue block spans
+    the first out_digits columns rounded up to a 128-lane row (or all n,
+    where that is narrower); the last batch block may run past ``rows``
+    (its rows are independent and their writes are dropped)."""
+    width = min(n, -(-out_digits // 128) * 128)
+    return pl.pallas_call(
+        make_crt_combine_kernel(nprimes, out_digits),
+        # shows as crt_combine/<nprimes> on the device trace
+        name="crt_combine",
+        grid=(pl.cdiv(rows, batch_tile),),
+        in_specs=[pl.BlockSpec((batch_tile, width), lambda i: (i, 0))
+                  for _ in range(nprimes)],
+        out_specs=pl.BlockSpec((batch_tile, out_digits), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, out_digits), U32),
         interpret=interpret,
     )

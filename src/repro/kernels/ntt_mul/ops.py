@@ -6,9 +6,9 @@ outside jit).  The pipeline per multiply:
 
   split to radix-2**16 digits, zero-pad to N = next_pow2(2 * ndigits)
   one fused kernel launch PER PRIME  ->  residue arrays mod p_i
-  Garner mixed-radix CRT (plain jnp -- elementwise Montgomery ops)
-  digit-column accumulation + ONE deferred-carry resolve
-  (kernels/common/carry.normalize_static)
+  one crt_combine kernel launch: Garner mixed-radix CRT (elementwise
+  Montgomery ops), digit-column accumulation and ONE deferred-carry
+  resolve (kernels/common/carry.normalize_static), in VMEM per tile
 
 Prime count: 2 primes give a CRT modulus ~2**56 -- exact for operands to
 ~2**24 digits (hundreds of megabits), far past the 64K-bit design point;
@@ -21,7 +21,7 @@ pre-reduction (r1 < p1 < p2, t2 < p2 < p3), and its mixed-radix digits
 (v = r1 + p1*t2 + p1*p2*t3) decompose into 16-bit half products against
 the HOST-known constant digits of p1 and p1*p2 -- every partial fits
 uint32, lazily accumulated into product columns with a worst case of 26
-terms per column (< 2**21, see test_ntt_mul's bound check) before the
+terms per column (< 2**21, ``kernel.CRT_COLUMN_TERMS``) before the
 single static carry resolve.
 """
 from __future__ import annotations
@@ -34,20 +34,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.common import autotune, tiling
-from repro.kernels.common.carry import normalize_static
 from repro.kernels.common.runtime import auto_interpret as _auto_interpret
 from repro.kernels.ntt_mul import kernel as K
 from repro.resilience import inject as _inject
 
 U32 = jnp.uint32
 R = 1 << K.R_BITS
-DIGIT_BITS = 16
-DMASK = np.uint32(0xFFFF)
-
-# Worst-case lazy terms landing on one CRT output column (2 from r1's
-# lo/hi, 8 from t2 x p1's 2x2 half products, 16 from t3 x (p1*p2)'s 2x4),
-# each < 2**16: the bound fed to the single normalize_static resolve.
-CRT_COLUMN_TERMS = 26
+DIGIT_BITS = K.DIGIT_BITS
+DMASK = K.DMASK
 
 
 def next_pow2(x: int) -> int:
@@ -220,91 +214,25 @@ def prepared_operand(value: int, n: int, nprimes: int) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# CRT recombination (plain jnp; reuses the kernel's elementwise mod ops).
+# CRT recombination (the crt_combine kernel).
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=8)
-def _garner_constants(nprimes: int) -> dict:
-    """Host-precomputed Montgomery constants for Garner recombination."""
-    p1, p2 = K.PRIMES[0], K.PRIMES[1]
-    c = {
-        "pinv2": (-pow(p2, -1, R)) % R,
-        "inv1_mont2": pow(p1, -1, p2) * R % p2,     # mont_mul -> * p1^-1
-        "p1_digits": tuple((p1 >> (16 * k)) & 0xFFFF for k in range(2)),
-    }
-    if nprimes >= 3:
-        p3 = K.PRIMES[2]
-        q = p1 * p2
-        c.update({
-            "pinv3": (-pow(p3, -1, R)) % R,
-            "p1_mont3": p1 * R % p3,                # mont_mul -> * p1
-            "inv12_mont3": pow(q, -1, p3) * R % p3,  # mont_mul -> * q^-1
-            "q_digits": tuple((q >> (16 * k)) & 0xFFFF for k in range(4)),
-        })
-    return c
+def _crt_tile(out_digits: int, rows: int) -> int:
+    tb = tiling.batch_tile(
+        out_digits, rows, budget=tiling.budget_words(K.CRT_LIVE_U32_ARRAYS),
+        max_tile=K.MAX_TILE)
+    return min(tb, rows)          # a block of fewer rows spans them all
 
 
-def crt_combine(residues, out_digits: int):
-    """Per-prime residue arrays (..., >= out_digits) -> (..., out_digits)
-    normalized radix-2**16 digits of the recombined coefficients.
-
-    Garner: v = r1 + p1*t2 (+ p1*p2*t3), every multiply against the
-    host-known constant digits of p1 / p1*p2 as 16-bit half products,
-    accumulated lazily (``_garner_columns``) and resolved with ONE static
-    carry pass.  On a device trace the two show as the named scopes
-    ``crt_combine/garner`` and ``crt_combine/carry_resolve``.
-    """
-    with jax.named_scope("crt_combine"):
-        with jax.named_scope("garner"):
-            cols = _garner_columns(residues, out_digits)
-        with jax.named_scope("carry_resolve"):
-            norm = normalize_static(cols, DIGIT_BITS,
-                                    bound=CRT_COLUMN_TERMS << DIGIT_BITS)
-        return norm[..., :out_digits]
-
-
-def _garner_columns(residues, out_digits: int):
-    """The lazy (unresolved) column sums of the Garner recombination."""
-    nprimes = len(residues)
-    c = _garner_constants(nprimes)
-    p2 = K.PRIMES[1]
-    r1 = residues[0][..., :out_digits]
-    t2 = K.mont_mul(
-        K.sub_mod(residues[1][..., :out_digits], r1, p2),
-        jnp.full((), np.uint32(c["inv1_mont2"]), U32), p2, c["pinv2"])
-
-    lead = r1.shape[:-1]
-    width = out_digits + 8                 # headroom for the top carries
-    cols = jnp.zeros(lead + (width,), U32)
-
-    def acc(cols, vals, off):
-        return cols.at[..., off:off + out_digits].add(vals)
-
-    def acc_prod(cols, t, const_digits):
-        tlo = t & DMASK
-        thi = t >> np.uint32(16)
-        for k, ck in enumerate(const_digits):
-            if ck == 0:
-                continue
-            for part, o in ((tlo, 0), (thi, 1)):
-                prod = part * np.uint32(ck)          # exact in uint32
-                cols = acc(cols, prod & DMASK, k + o)
-                cols = acc(cols, prod >> np.uint32(16), k + o + 1)
-        return cols
-
-    cols = acc(cols, r1 & DMASK, 0)
-    cols = acc(cols, r1 >> np.uint32(16), 1)
-    cols = acc_prod(cols, t2, c["p1_digits"])
-    if nprimes >= 3:
-        p3 = K.PRIMES[2]
-        c12 = K.add_mod(
-            r1, K.mont_mul(t2, jnp.full((), np.uint32(c["p1_mont3"]), U32),
-                           p3, c["pinv3"]), p3)
-        t3 = K.mont_mul(
-            K.sub_mod(residues[2][..., :out_digits], c12, p3),
-            jnp.full((), np.uint32(c["inv12_mont3"]), U32), p3, c["pinv3"])
-        cols = acc_prod(cols, t3, c["q_digits"])
-    return cols
+def crt_combine(residues, out_digits: int, interpret=None):
+    """Per-prime residue arrays (rows, >= out_digits) -> (rows,
+    out_digits) normalized radix-2**16 digits of the recombined
+    coefficients, mod 2**(16 * out_digits): one ``crt_combine`` kernel
+    launch (Garner and the carry resolve in one VMEM pass per tile)."""
+    rows, n = residues[0].shape
+    interpret = _auto_interpret(interpret)
+    return K.make_crt_call(_crt_tile(out_digits, rows), rows, n, out_digits,
+                           len(residues), interpret)(*residues)
 
 
 # ---------------------------------------------------------------------------
@@ -326,11 +254,9 @@ def _call(a_d, b_d, twiddles, nprimes: int, tb: int, interpret: bool):
     a_p = jnp.pad(a_d, ((0, pad_b), (0, n - nd)))
     b_p = jnp.pad(b_d, ((0, pad_b), (0, n - nd)))
     grid = a_p.shape[0] // tb
-    residues = []
-    for p, (wf, wi) in zip(K.PRIMES[:nprimes], twiddles):
-        r = K.make_call(tb, n, grid, p, interpret)(a_p, b_p, wf, wi)
-        residues.append(r[:batch])
-    return crt_combine(residues, 2 * nd)
+    residues = [K.make_call(tb, n, grid, p, interpret)(a_p, b_p, wf, wi)
+                for p, (wf, wi) in zip(K.PRIMES[:nprimes], twiddles)]
+    return crt_combine(residues, 2 * nd, interpret)[:batch]
 
 
 def ntt_mul_digits(a_digits, b_digits, nprimes: int | None = None,
@@ -376,11 +302,10 @@ def _call_prepared(a_d, fb_rows, twiddles, nprimes: int, tb: int,
     pad_b = (-batch) % tb
     a_p = jnp.pad(a_d, ((0, pad_b), (0, n - nd)))
     grid = a_p.shape[0] // tb
-    residues = []
-    for p, fb, (wf, wi) in zip(K.PRIMES[:nprimes], fb_rows, twiddles):
-        r = K.make_prepared_call(tb, n, grid, p, interpret)(a_p, fb, wf, wi)
-        residues.append(r[:batch])
-    return crt_combine(residues, 2 * nd)
+    residues = [
+        K.make_prepared_call(tb, n, grid, p, interpret)(a_p, fb, wf, wi)
+        for p, fb, (wf, wi) in zip(K.PRIMES[:nprimes], fb_rows, twiddles)]
+    return crt_combine(residues, 2 * nd, interpret)[:batch]
 
 
 def ntt_mul_digits_prepared(a_digits, b_value: int,

@@ -7,12 +7,18 @@ truth directly so a kernel bug and a core/mul.py bug cannot cancel.
 ``ntt_fwd_ref`` is an O(N**2) Python-int DFT used to pin down the
 transform itself (twiddle tables, stage order, bit-reversed layout)
 independently of the inverse that would undo a systematic error.
+``crt_combine_ref`` is the plain-jnp Garner recombination (scatter-add
+columns, one carry resolve) the ``crt_combine`` kernel replaced; the
+tests hold the kernel to it bit for bit.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mul import mul_karatsuba, mul_limbs32
+from repro.kernels.common.carry import normalize_static
+from repro.kernels.ntt_mul import kernel as K
 from repro.kernels.ntt_mul.kernel import GENERATOR
 
 
@@ -42,3 +48,43 @@ def ntt_fwd_ref(x, p: int) -> np.ndarray:
     bits = n.bit_length() - 1
     return np.array([nat[_bit_reverse(i, bits)] for i in range(n)],
                     np.uint32)
+
+
+def crt_combine_ref(residues, out_digits: int):
+    """Per-prime residues (..., >= out_digits) -> (..., out_digits)
+    normalized digits, in plain jnp: v = r1 + p1*t2 (+ p1*p2*t3) as
+    16-bit half products scatter-added into lazy columns, then one
+    ``normalize_static`` resolve."""
+    nprimes = len(residues)
+    c = K.garner_constants(nprimes)
+    p2 = K.PRIMES[1]
+    u32 = np.uint32
+    r1 = residues[0][..., :out_digits]
+    t2 = K.mont_mul(K.sub_mod(residues[1][..., :out_digits], r1, p2),
+                    u32(c["inv1_mont2"]), p2, c["pinv2"])
+    cols = jnp.zeros(r1.shape[:-1] + (out_digits + 8,), jnp.uint32)
+
+    def acc(cols, vals, off):
+        return cols.at[..., off:off + out_digits].add(vals)
+
+    def acc_prod(cols, t, const_digits):
+        for k, ck in enumerate(const_digits):
+            for part, o in ((t & K.DMASK, 0), (t >> u32(16), 1)):
+                prod = part * u32(ck)
+                cols = acc(cols, prod & K.DMASK, k + o)
+                cols = acc(cols, prod >> u32(16), k + o + 1)
+        return cols
+
+    cols = acc(cols, r1 & K.DMASK, 0)
+    cols = acc(cols, r1 >> u32(16), 1)
+    cols = acc_prod(cols, t2, c["p1_digits"])
+    if nprimes >= 3:
+        p3 = K.PRIMES[2]
+        c12 = K.add_mod(r1, K.mont_mul(t2, u32(c["p1_mont3"]), p3,
+                                       c["pinv3"]), p3)
+        t3 = K.mont_mul(K.sub_mod(residues[2][..., :out_digits], c12, p3),
+                        u32(c["inv12_mont3"]), p3, c["pinv3"])
+        cols = acc_prod(cols, t3, c["q_digits"])
+    norm = normalize_static(cols, K.DIGIT_BITS,
+                            bound=K.CRT_COLUMN_TERMS << K.DIGIT_BITS)
+    return norm[..., :out_digits]

@@ -1,8 +1,9 @@
 """NTT/CRT huge-operand multiply subsystem (kernels/ntt_mul) vs Python-int
 ground truth, plus the layers under it: the uint32-only wide-multiply /
 Montgomery primitives, the twiddle tables, the forward transform against
-an O(N^2) DFT oracle, Garner CRT recombination, and the core/mul.py
-dispatch tier that routes huge operands here.
+an O(N^2) DFT oracle, the crt_combine kernel (Garner CRT recombination)
+against Python ints and its jnp reference, and the core/mul.py dispatch
+tier that routes huge operands here.
 
 Oracle widths follow the CI fast-subset policy: 4096/8192-bit oracles run
 on PRs, the >= 16384-bit grid (where a single interpret-mode launch still
@@ -121,7 +122,7 @@ def test_forward_inverse_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# Garner CRT recombination vs Python ints.
+# The crt_combine kernel (Garner CRT recombination) vs Python ints.
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("nprimes", [2, 3])
@@ -142,6 +143,69 @@ def test_crt_combine_matches_python(nprimes):
     got = np.asarray(NO.crt_combine(res, nd_out))[0]
     assert got.max() <= 0xFFFF
     assert L.limbs_to_int(got, 16) == want % (1 << (16 * nd_out))
+
+
+def _crt_residues(coeffs, nprimes, width):
+    """Rows of python-int coefficients -> per-prime (rows, width) residue
+    arrays, zero beyond the coefficients (as an NTT's padded tail)."""
+    return tuple(
+        jnp.asarray(np.array([[v % p for v in row] + [0] * (width - len(row))
+                              for row in coeffs], np.uint32))
+        for p in NK.PRIMES[:nprimes])
+
+
+def _check_crt(coeffs, nprimes, nd_out, width):
+    got = np.asarray(NO.crt_combine(_crt_residues(coeffs, nprimes, width),
+                                    nd_out))
+    assert got.shape == (len(coeffs), nd_out)
+    assert got.max() <= 0xFFFF
+    for i, row in enumerate(coeffs):
+        want = sum(v << (16 * j) for j, v in enumerate(row))
+        assert L.limbs_to_int(got[i], 16) == want % (1 << (16 * nd_out)), i
+
+
+@pytest.mark.parametrize("nprimes", [2, 3])
+def test_crt_combine_worst_case_coefficients(nprimes):
+    """Every coefficient of every column at coefficient_bound - 1: the
+    largest Garner digits on every column at once, and the longest
+    carry runs through the resolve."""
+    nd_out = 256
+    top = NO.coefficient_bound(nd_out) - 1
+    _check_crt([[top] * nd_out] * 8, nprimes, nd_out, nd_out)
+
+
+@pytest.mark.parametrize("nprimes", [2, 3])
+def test_crt_combine_batch_not_a_tile_multiple(nprimes):
+    nd_out, rows = 64, 13
+    assert rows % NO._crt_tile(nd_out, rows) != 0   # a partial last block
+    bound = NO.coefficient_bound(nd_out)
+    coeffs = [[int(RNG.integers(0, 1 << 62)) % bound for _ in range(nd_out)]
+              for _ in range(rows)]
+    _check_crt(coeffs, nprimes, nd_out, 2 * nd_out)
+
+
+@pytest.mark.parametrize("nprimes", [2, 3])
+def test_crt_combine_width_not_a_lane_multiple(nprimes):
+    """328 output digits out of 1024-wide residues: the kernel reads a
+    384-column block and keeps 328 columns of it."""
+    nd_out = 328
+    bound = NO.coefficient_bound(nd_out)
+    coeffs = [[int(RNG.integers(0, 1 << 62)) % bound for _ in range(nd_out)]
+              for _ in range(8)]
+    _check_crt(coeffs, nprimes, nd_out, 1024)
+
+
+@pytest.mark.parametrize("nprimes", [2, 3])
+def test_crt_combine_matches_jnp_ref(nprimes):
+    """Uniform residues, CRT-consistent or not, equal the plain-jnp
+    Garner recombination bit for bit."""
+    nd_out, rows = 256, 16
+    res = tuple(jnp.asarray(RNG.integers(0, p, (rows, 2 * nd_out),
+                                         dtype=np.int64).astype(np.uint32))
+                for p in NK.PRIMES[:nprimes])
+    np.testing.assert_array_equal(np.asarray(NO.crt_combine(res, nd_out)),
+                                  np.asarray(NREF.crt_combine_ref(res,
+                                                                  nd_out)))
 
 
 def test_resolve_nprimes_validation():

@@ -111,17 +111,29 @@ def _ntt(s, bits, batch=64):
                                interpret=False)
 
 
-CASES = {   # case: (build, bits, the kernel's kernel_name)
-    "montgomery_ladder-1024": (_ladder, 1024, "ladder_kernel"),  # CRT halves
-    "montgomery_ladder-2048": (_ladder, 2048, "ladder_kernel"),
+def _crt(s, bits, batch=4096):
+    nd = bits // 16
+    n = ntt_ops.next_pow2(2 * nd)
+    nprimes = ntt_ops._resolve_nprimes(nd, None)
+    res = [_shape(s, batch, n) for _ in range(nprimes)]
+    return jax.jit(lambda *r: ntt_ops.crt_combine(
+        r, 2 * nd, interpret=False)).lower(*res)
+
+
+CASES = {   # case: (build, bits, the kernel_names of its kernels)
+    # the CRT halves of an RSA-2048 key
+    "montgomery_ladder-1024": (_ladder, 1024, {"ladder_kernel"}),
+    "montgomery_ladder-2048": (_ladder, 2048, {"ladder_kernel"}),
     "barrett_ladder-1024": (_barrett_ladder, 1024,
-                            "dot_modmul_barrett_ladder"),
-    "kara_mul-1024": (_kara, 1024, "kara_kernel"),
-    "kara_mul-2048": (_kara, 2048, "kara_kernel"),
-    "kara_mul-4096": (_kara, 4096, "kara_kernel"),
-    "dot_mul-512": (_dot_mul, 512, "dot_mul"),
-    "dot_div-512": (_dot_div, 512, "dot_div"),
-    "ntt_mul-16384": (_ntt, 16384, "ntt_mul_kernel"),
+                            {"dot_modmul_barrett_ladder"}),
+    "kara_mul-1024": (_kara, 1024, {"kara_kernel"}),
+    "kara_mul-2048": (_kara, 2048, {"kara_kernel"}),
+    "kara_mul-4096": (_kara, 4096, {"kara_kernel"}),
+    "dot_mul-512": (_dot_mul, 512, {"dot_mul"}),
+    "dot_div-512": (_dot_div, 512, {"dot_div"}),
+    # one launch per prime, then the CRT recombination
+    "ntt_mul-16384": (_ntt, 16384, {"ntt_mul_kernel", "crt_combine"}),
+    "crt_combine-16384": (_crt, 16384, {"crt_combine"}),
 }
 
 
@@ -134,10 +146,10 @@ def test_kernel_compiles_for_v5e(one_chip, case):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_lowered_kernel_carries_its_name(one_chip, case):
-    build, bits, name = CASES[case]
+    build, bits, want = CASES[case]
     text = build(one_chip, bits).as_text()
     names = re.findall(r'kernel_name = "([^"]*)"', text)
-    assert names and set(names) == {name}
+    assert set(names) == want
 
 
 def _rsa_sign_512(s):
@@ -151,7 +163,8 @@ KEYED = {   # program: (build, the kernels' <instruction>/<operand count>)
                  ["_call/2"]),                   # kara_mul
     "mul-8192": (lambda s: jax.jit(api.mul).lower(_shape(s, 8, 256),
                                                   _shape(s, 8, 256)),
-                 ["_call/4", "_call/4"]),        # ntt_mul, one per prime
+                 # ntt_mul, one per prime, then the CRT recombination
+                 ["_call/4", "_call/4", "crt_combine/2"]),
     "rsa_sign-512": (_rsa_sign_512, ["_ladder_call/5"]),
 }
 
@@ -180,11 +193,9 @@ def mul_8192_op_names(one_chip):
     return set(re.findall(r'op_name="([^"]*)"', text))
 
 
-@pytest.mark.parametrize("scope", ["radix_split", "radix_join",
-                                   "crt_combine/garner",
-                                   "crt_combine/carry_resolve"])
+@pytest.mark.parametrize("scope", ["radix_split", "radix_join"])
 def test_compiled_mul_carries_named_scopes(mul_8192_op_names, scope):
-    # the jnp compositions around the ntt kernels keep their named scopes
+    # the jnp radix conversions around the ntt kernels keep their scopes
     # in the compiled program's op_name metadata, where a reader of the
     # compiled HLO attributes the device ops to them
     assert any(f"/{scope}/" in n for n in mul_8192_op_names)
