@@ -53,12 +53,15 @@ exp_bits_msb = _M.exp_bits_msb
 generate_key = _rsa.generate_key
 digest_int = _rsa.digest_int
 RSAKey = _rsa.RSAKey
+KeyLanes = _rsa.KeyLanes
+key_lanes = _rsa.key_lanes
 
 __all__ = [
     "mul", "divmod", "mod_exp", "rsa_sign", "rsa_verify", "rsa_decrypt",
     "to_decimal", "configure", "cache_stats", "metrics", "dispatch_report",
     "to_limbs", "from_limbs",
     "mod_setup", "exp_bits_msb", "generate_key", "digest_int", "RSAKey",
+    "KeyLanes", "key_lanes",
 ]
 
 
@@ -224,18 +227,24 @@ def mod_exp(base, exponent, modulus, *, backend: str | None = None,
 # RSA front doors
 # ---------------------------------------------------------------------------
 
-def rsa_sign(msg, key: "_rsa.RSAKey", *, backend: str | None = None
-             ) -> jax.Array:
-    """s = m ** d mod n on (..., ma) uint32 limbs (ma = ceil(bits/32))."""
+def rsa_sign(msg, key: "_rsa.RSAKey | _rsa.KeyLanes", *,
+             backend: str | None = None) -> jax.Array:
+    """s = m ** d mod n on (..., ma) uint32 limbs (ma = ceil(bits/32)).
+
+    ``key``: one ``RSAKey`` for every lane, or a ``KeyLanes``
+    (``key_lanes(keys)``) of keys of one width, one a lane of an (N, ma)
+    batch: lane i is then signed under key i, in the same batch and the
+    same compiled program."""
     ctx = key.ctx
     d = _digits_from_limbs(msg, ctx.m)
     return _limbs_from_digits(_rsa.sign(d, key, backend=backend),
                               _limb_width(ctx))
 
 
-def rsa_verify(sig, key: "_rsa.RSAKey", *, backend: str | None = None
-               ) -> jax.Array:
-    """m = s ** e mod n on (..., ma) uint32 limbs."""
+def rsa_verify(sig, key: "_rsa.RSAKey | _rsa.KeyLanes", *,
+               backend: str | None = None) -> jax.Array:
+    """m = s ** e mod n on (..., ma) uint32 limbs; ``key`` as for
+    ``rsa_sign``."""
     ctx = key.ctx
     d = _digits_from_limbs(sig, ctx.m)
     return _limbs_from_digits(_rsa.verify(d, key, backend=backend),
@@ -246,7 +255,10 @@ def rsa_decrypt(cipher, key: "_rsa.RSAKey", *, backend: str | None = None,
                 crt: bool = True) -> jax.Array:
     """m = c ** d mod n on (..., ma) uint32 limbs.  ``crt=True`` (needs
     a key with known p, q) runs the two half-size CRT modexps; False
-    falls back to the full-width ladder (== rsa_sign)."""
+    falls back to the full-width ladder (== rsa_sign).  One key for the
+    whole batch."""
+    if not isinstance(key, _rsa.RSAKey):
+        raise TypeError("rsa_decrypt takes one RSAKey for the whole batch")
     ctx = key.ctx
     d = _digits_from_limbs(cipher, ctx.m)
     if crt:

@@ -45,6 +45,14 @@ Every public op takes ``backend`` (default: the module default, "jnp"):
 
 core/rsa.py, examples/rsa_crypto.py and benchmarks/bench_crypto.py all
 route through this one API, so backends can be compared head-to-head.
+
+Per-lane moduli
+---------------
+A ``MontLanes`` context holds one odd modulus per lane, all of one width:
+lane i of mont_mul / mod_mul / mod_exp then works modulo its own n_i.
+The reference, jnp and pallas backends take it (the Montgomery kernels
+take every modulus and its constants as run-time operands); the Barrett
+backends, which take one modulus a batch, refuse it.
 """
 from __future__ import annotations
 
@@ -93,6 +101,9 @@ def _resolve_backend(backend: str | None, ctx=None) -> str:
             return "barrett_fused"
         if backend == "jnp":
             return "barrett"
+    if isinstance(ctx, MontLanes) and backend in ("barrett",
+                                                  "barrett_fused"):
+        raise ValueError(_LANES_NOT_BARRETT)
     return backend
 
 
@@ -105,6 +116,41 @@ class MontCtx:
     n_digits: np.ndarray         # (m,)
     r2_digits: np.ndarray        # R^2 mod n   (to enter Montgomery form)
     one_digits: np.ndarray       # R mod n     (Montgomery form of 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class MontLanes:
+    """Montgomery constants per lane: lane i of a batch works modulo its
+    own odd n_i, every n_i of width m digits (R = B**m for all lanes).
+    The arrays may be traced values, so a program can gather its lanes'
+    rows from a key table on the device (serve/bignum_engine)."""
+    m: int                       # digits
+    n_digits: jax.Array          # (N, m) each lane's modulus
+    r2_digits: jax.Array         # (N, m) R^2 mod n_i
+    n0p: jax.Array               # (N,) uint32 -n_i^{-1} mod B
+
+
+_LANES_NOT_BARRETT = (
+    "a per-lane Montgomery context (MontLanes) runs on the reference, jnp "
+    "and pallas backends; the Barrett backends take one modulus a batch")
+
+
+def mont_lanes(moduli, nbits: int) -> MontLanes:
+    """Host-built per-lane context for odd ``moduli`` of one width."""
+    ctxs = [mont_setup(int(n), nbits) for n in moduli]
+    return MontLanes(
+        m=ctxs[0].m,
+        n_digits=np.stack([c.n_digits for c in ctxs]),
+        r2_digits=np.stack([c.r2_digits for c in ctxs]),
+        n0p=np.array([c.n0p for c in ctxs], np.uint32))
+
+
+def _lane_moduli(ctx, lanes: int) -> list:
+    """The python-int modulus of each of ``lanes`` lanes (host values)."""
+    if isinstance(ctx, MontLanes):
+        rows = np.asarray(ctx.n_digits, np.uint32).reshape(-1, ctx.m)
+        return [L.limbs_to_int(r, DIGIT_BITS) for r in rows]
+    return [ctx.n] * lanes
 
 
 @functools.lru_cache(maxsize=128)
@@ -184,6 +230,8 @@ def mod_setup(n: int, nbits: int | None = None):
 def _as_barrett(ctx) -> BarrettCtx:
     if isinstance(ctx, BarrettCtx):
         return ctx
+    if isinstance(ctx, MontLanes):
+        raise ValueError(_LANES_NOT_BARRETT)
     return barrett_setup(ctx.n, ctx.m * DIGIT_BITS)   # memoized setup
 
 
@@ -284,11 +332,13 @@ def _flatten_batch(x: jax.Array, m: int):
     return x.reshape((-1, m)), batch_shape
 
 
-def _mont_mul_jnp(a: jax.Array, b: jax.Array, ctx: MontCtx,
+def _mont_mul_jnp(a: jax.Array, b: jax.Array, ctx,
                   lazy: bool = True) -> jax.Array:
     """CIOS Montgomery product: a*b*R^{-1} mod n (pure-jnp backend).
 
-    a, b: (..., m) normalized digits < 2**16, values < n.
+    a, b: (..., m) normalized digits < 2**16, values < n.  ``ctx`` holds
+    one modulus (MontCtx) or one per lane (MontLanes, whose rows line up
+    with the batch).
     Sequential over the m digits of a (inherent to Montgomery); fully
     vectorized over the batch and the m-digit vector ops per iteration;
     digits stay lazy (deferred carries) across all iterations.
@@ -298,8 +348,8 @@ def _mont_mul_jnp(a: jax.Array, b: jax.Array, ctx: MontCtx,
     uses it as the integration baseline (paper sec 4.4).
     """
     m = ctx.m
-    n_dig = jnp.asarray(ctx.n_digits, U32)
-    n0p = jnp.uint32(ctx.n0p)
+    n_dig = jnp.asarray(ctx.n_digits, U32)       # (m,) or (N, m)
+    n0p = jnp.asarray(ctx.n0p, U32)              # () or (N,)
     bits = jnp.uint32(DIGIT_BITS)
 
     a = jnp.asarray(a, U32)
@@ -337,14 +387,15 @@ def _mont_mul_jnp(a: jax.Array, b: jax.Array, ctx: MontCtx,
     acc, _ = jax.lax.scan(step, acc0, a_t)
     acc = normalize_digits(acc, DIGIT_BITS)           # (..., m+1), t < 2n
     # conditional subtract: t >= n -> t - n
-    n_ext = jnp.concatenate([n_dig, jnp.zeros((1,), U32)])
+    n_ext = jnp.concatenate(
+        [n_dig, jnp.zeros(n_dig.shape[:-1] + (1,), U32)], axis=-1)
     ge = _ge(acc, jnp.broadcast_to(n_ext, acc.shape))
     sub = _sub_mod(acc, n_ext)[..., : m + 1]
     out = jnp.where(ge[..., None], sub, acc)
     return out[..., :m]
 
 
-def _mont_mul_reference(a, b, ctx: MontCtx) -> jax.Array:
+def _mont_mul_reference(a, b, ctx) -> jax.Array:
     """Host-side Python-int oracle (exact; defines correctness)."""
     from repro.kernels.dot_modmul import ref as _ref
     a = np.asarray(a, np.uint32)
@@ -352,7 +403,7 @@ def _mont_mul_reference(a, b, ctx: MontCtx) -> jax.Array:
     shape = np.broadcast_shapes(a.shape[:-1], b.shape[:-1]) + (ctx.m,)
     a2, batch_shape = _flatten_batch(np.broadcast_to(a, shape), ctx.m)
     b2, _ = _flatten_batch(np.broadcast_to(b, shape), ctx.m)
-    out = _ref.mont_mul_ref(a2, b2, ctx.n)
+    out = _ref.mont_mul_ref(a2, b2, _lane_moduli(ctx, a2.shape[0]))
     return jnp.asarray(out.reshape(batch_shape + (ctx.m,)))
 
 
@@ -515,7 +566,11 @@ def _mod_exp_jnp(base: jax.Array, exp_bits: jax.Array, ctx: MontCtx,
                  lazy: bool = True, window: int | None = None,
                  unroll: bool = False) -> jax.Array:
     x = to_mont(jnp.asarray(base, U32), ctx, backend="jnp")
-    one = jnp.asarray(ctx.one_digits, U32)
+    if isinstance(ctx, MontLanes):
+        # R mod n_i per lane, REDC(R^2 * 1), as the ladder kernel makes it
+        one = from_mont(jnp.asarray(ctx.r2_digits, U32), ctx, backend="jnp")
+    else:
+        one = jnp.asarray(ctx.one_digits, U32)
     res = _windowed_ladder(
         lambda a, b: _mont_mul_jnp(a, b, ctx, lazy), one, x, exp_bits,
         window, unroll=unroll)
@@ -529,31 +584,41 @@ def _bits_to_int(bits: np.ndarray) -> int:
     return e
 
 
-def _mod_exp_reference(base, exp_bits, ctx: MontCtx) -> jax.Array:
+def _mod_exp_lanes_ref(b2: np.ndarray, eb: np.ndarray,
+                       moduli: list) -> np.ndarray:
+    """(N, m) bases, MSB-first exponent bits ((nbits,) or (N, nbits)) and
+    one python-int modulus a lane -> (N, m) digits of b_i**e_i mod n_i."""
     from repro.kernels.dot_modmul import ref as _ref
+    lanes = b2.shape[0]
+    eb = np.asarray(eb, np.uint32)
+    exps = ([_bits_to_int(eb)] * lanes if eb.ndim == 1 else
+            [_bits_to_int(r) for r in eb.reshape(lanes, eb.shape[-1])])
+    return _ref.mod_exp_ref_lanes(b2, exps, moduli)
+
+
+def _mod_exp_reference(base, exp_bits, ctx) -> jax.Array:
     base = np.asarray(base, np.uint32)
     eb = np.asarray(exp_bits, np.uint32)
     b2, batch_shape = _flatten_batch(base, ctx.m)
-    if eb.ndim == 1:
-        out = _ref.mod_exp_ref(b2, _bits_to_int(eb), ctx.n)
-    else:
-        eb2 = np.broadcast_to(eb, batch_shape + (eb.shape[-1],))
-        eb2 = eb2.reshape((-1, eb.shape[-1]))
-        out = np.stack(
-            [_ref.mod_exp_ref(b2[i:i + 1], _bits_to_int(eb2[i]), ctx.n)[0]
-             for i in range(b2.shape[0])])
+    if eb.ndim > 1:
+        eb = np.broadcast_to(eb, batch_shape + (eb.shape[-1],))
+    out = _mod_exp_lanes_ref(b2, eb, _lane_moduli(ctx, b2.shape[0]))
     return jnp.asarray(out.reshape(batch_shape + (ctx.m,)))
 
 
 def _mod_exp_reference_cb(b2: jax.Array, eb: jax.Array, ctx) -> jax.Array:
     """The host oracle as a jit-safe tier: the guarded dispatchers run at
-    trace time, where b2/eb are tracers, so the python-int recompute is
-    deferred to runtime via pure_callback."""
-    def _host(base_np, eb_np):
-        return np.asarray(_mod_exp_reference(base_np, eb_np, ctx),
-                          np.uint32)
+    trace time, where b2/eb (and a per-lane context's rows) are tracers,
+    so the python-int recompute is deferred to runtime via pure_callback,
+    with each lane's modulus passed in beside its base."""
+    n_rows = jnp.broadcast_to(jnp.asarray(ctx.n_digits, U32), b2.shape)
+
+    def _host(base_np, eb_np, n_np):
+        moduli = [L.limbs_to_int(r, DIGIT_BITS) for r in np.asarray(n_np)]
+        return _mod_exp_lanes_ref(np.asarray(base_np), eb_np,
+                                  moduli).astype(np.uint32)
     shape = jax.ShapeDtypeStruct(b2.shape[:-1] + (ctx.m,), np.uint32)
-    return jax.pure_callback(_host, shape, b2, eb)
+    return jax.pure_callback(_host, shape, b2, eb, n_rows)
 
 
 def select_modexp_backend(nbits: int, batch: int = 1, ebits: int = 0,

@@ -5,6 +5,11 @@ bound crypto, batched across TPU lanes.  Key generation runs host-side
 with Python integers (Miller-Rabin) -- the launcher's job, like loading
 certificates; all per-message math runs in JAX via core.modular.
 
+sign and verify take one key for the whole batch (``RSAKey``) or one key a
+lane (``KeyLanes``: keys of one width, lane i signing with d_i modulo
+n_i), so a server with many certificates fills one batch and one
+compiled program with all of them.
+
 This module also provides the checkpoint-integrity signer used by
 train/checkpoint.py (dogfooding: the framework's own fault-tolerance
 layer rides on the paper's arithmetic).
@@ -78,6 +83,45 @@ class RSAKey:
         return M.mont_setup(self.n, self.bits)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class KeyLanes:
+    """RSA keys of one width, one a lane: lane i signs with d_i and
+    verifies with e_i modulo n_i.  A pytree of arrays (``bits`` is
+    static), so a key table can be a jitted program's argument and
+    ``take`` gathers each lane's rows inside the program."""
+    n_digits: jax.Array          # (N, m) modulus digits
+    r2_digits: jax.Array         # (N, m) R^2 mod n
+    n0p: jax.Array               # (N,) -n^{-1} mod B
+    d_bits: jax.Array            # (N, bits) d, MSB first, padded to bits
+    e_bits: jax.Array            # (N, ebits) e, MSB first
+    bits: int = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def ctx(self) -> M.MontLanes:
+        return M.MontLanes(m=self.n_digits.shape[-1], n_digits=self.n_digits,
+                           r2_digits=self.r2_digits, n0p=self.n0p)
+
+    def take(self, idx) -> "KeyLanes":
+        """The keys at rows ``idx`` ((N,) ints), one a lane."""
+        return jax.tree_util.tree_map(lambda x: x[idx], self)
+
+
+def key_lanes(keys) -> KeyLanes:
+    """One row a key, on the host, for keys of one width."""
+    bits = keys[0].bits
+    if any(k.bits != bits for k in keys):
+        raise ValueError(f"key_lanes: keys of one width only, got "
+                         f"{sorted({k.bits for k in keys})} bits")
+    ctx = M.mont_lanes([k.n for k in keys], bits)
+    ebits = max(k.e.bit_length() for k in keys)
+    return KeyLanes(
+        n_digits=ctx.n_digits, r2_digits=ctx.r2_digits, n0p=ctx.n0p,
+        d_bits=np.stack([M.exp_bits_msb(k.d, bits) for k in keys]),
+        e_bits=np.stack([M.exp_bits_msb(k.e, ebits) for k in keys]),
+        bits=bits)
+
+
 def generate_key(bits: int = 512, seed: int = 0, e: int = 65537) -> RSAKey:
     rng = np.random.default_rng(seed)
     while True:
@@ -105,25 +149,30 @@ def messages_to_digits(msgs: list[int], key: RSAKey) -> jnp.ndarray:
         [L.int_to_limbs(msg % key.n, m_digits, DIGIT_BITS) for msg in msgs]))
 
 
-def sign(msg_digits: jax.Array, key: RSAKey,
+def sign(msg_digits: jax.Array, key: RSAKey | KeyLanes,
          backend: str | None = None) -> jax.Array:
-    """s = m^d mod n, batched over leading axes.
+    """s = m^d mod n, batched over leading axes (with ``KeyLanes``, one
+    (N, m) batch whose lane i is signed under key i).
 
     ``backend=None`` routes through core/modular's batch-aware modexp
     dispatch (MODEXP_DISPATCH): the fused full-ladder Pallas kernel for
     kernel-sized batches, the jnp windowed ladder below that."""
-    bits = M.exp_bits_msb(key.d, key.n.bit_length())
-    return M.mod_exp(msg_digits, jnp.asarray(bits), key.ctx,
-                     backend=backend)
+    if isinstance(key, KeyLanes):
+        bits = key.d_bits
+    else:
+        bits = jnp.asarray(M.exp_bits_msb(key.d, key.n.bit_length()))
+    return M.mod_exp(msg_digits, bits, key.ctx, backend=backend)
 
 
-def verify(sig_digits: jax.Array, key: RSAKey,
+def verify(sig_digits: jax.Array, key: RSAKey | KeyLanes,
            backend: str | None = None) -> jax.Array:
     """m = s^e mod n (fast public exponent; the windowed ladder picks a
     small window for the 17-bit e, see pick_modexp_window)."""
-    bits = M.exp_bits_msb(key.e)
-    return M.mod_exp(sig_digits, jnp.asarray(bits), key.ctx,
-                     backend=backend)
+    if isinstance(key, KeyLanes):
+        bits = key.e_bits
+    else:
+        bits = jnp.asarray(M.exp_bits_msb(key.e))
+    return M.mod_exp(sig_digits, bits, key.ctx, backend=backend)
 
 
 def decrypt_crt(c_digits: jax.Array, key: RSAKey,

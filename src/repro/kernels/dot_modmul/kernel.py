@@ -20,9 +20,12 @@ After m iterations: digits < 5*m*2**16 (safe in uint32 for m <= 2**13),
 one normalize_static pass brings t < 2n to normalized digits, and the
 radix-complement subtract selects t or t - n without branching.
 
-n0p and m are BAKED into the kernel (host-side Montgomery constants --
-one specialization per modulus, exactly the serving pattern: a key is
-loaded once, then millions of modmuls reuse the compiled kernel).
+Only the width m (and the ladder's window geometry) is baked into the
+kernel.  The modulus and its constants arrive per lane as run-time
+operands -- n and R^2 mod n as (TB, m) blocks, n0p = -n^{-1} mod B as a
+(TB, 1) column -- so lane i of a batch works modulo its own n_i and one
+compiled kernel serves every key of a width.  (The Barrett kernels below
+take their modulus as run-time operands too, one (1, m) row a batch.)
 
 ``make_ladder_call`` composes the same multiply into the fused
 full-ladder windowed modexp kernel: ONE launch runs the entire k-ary
@@ -53,13 +56,15 @@ DBITS = np.uint32(16)
 # product temps, normalize temps) + headroom; sizes the batch tile via
 # common/tiling.
 LIVE_U32_ARRAYS = 12
+# the ladder's per-lane modulus and R^2 blocks, beside the CIOS set
+LANE_ROW_ARRAYS = 2
 MAX_TILE = 256
 
 
 def cond_subtract(t, n):
     """Branch-free conditional subtract: t if t < n else t - n.
 
-    t: (TB, m+1) normalized digits with t < 2n; n: (1, m) or (TB, m).
+    t: (TB, m+1) normalized digits with t < 2n; n: (TB, m).
     Radix-complement add computes t - n + B**(m+1); the carry out of the
     top digit (1 iff t >= n) selects between the two candidates.
     """
@@ -75,7 +80,8 @@ def cond_subtract(t, n):
 
 
 def cios_iterations(a, b, n, n0p):
-    """The lazy CIOS loop on (TB, m) blocks; returns the (TB, m+1) lazy
+    """The lazy CIOS loop on (TB, m) blocks, each lane modulo its own row
+    of n with its own n0p (a (TB, 1) column); returns the (TB, m+1) lazy
     accumulator with t = a*b*R^{-1} represented in deferred-carry digits.
 
     The digit loop (the dependency chain inherent to Montgomery) is a
@@ -85,7 +91,6 @@ def cios_iterations(a, b, n, n0p):
     once per iteration (Mosaic has no dynamic lane slice).
     """
     tb, m = a.shape
-    n0p = np.uint32(n0p)
     lane0 = jax.lax.broadcasted_iota(jnp.int32, (tb, m + 1), 1) == 0
     z1 = jnp.zeros((tb, 1), U32)
 
@@ -116,14 +121,14 @@ def mont_mul_block(a, b, n, n0p):
     return cond_subtract(normalize_static(acc), n)
 
 
-def make_mont_kernel(m: int, n0p: int):
-    """Kernel body specialized to a modulus width m and constant n0p."""
+def make_mont_kernel():
+    """Kernel body of the single Montgomery multiply: the modulus and
+    n0p arrive per lane, the width is the blocks' own."""
 
-    def mont_mul_kernel(a_ref, b_ref, n_ref, out_ref):
-        a = a_ref[...]                            # (TB, m) digits < 2**16
-        b = b_ref[...]
-        n = n_ref[...]                            # (1, m) modulus digits
-        out_ref[...] = mont_mul_block(a, b, n, n0p)
+    def mont_mul_kernel(a_ref, b_ref, n_ref, n0p_ref, out_ref):
+        out_ref[...] = mont_mul_block(
+            a_ref[...], b_ref[...],               # (TB, m) digits < 2**16
+            n_ref[...], n0p_ref[...])             # (TB, m), (TB, 1)
 
     return mont_mul_kernel
 
@@ -142,11 +147,12 @@ def select_window(table, wins):
 def ladder_live_arrays(window: int) -> int:
     """Live (TB, ~m) uint32 arrays in the fused ladder kernel: the
     2**w-row power table dominates, plus the same ~12 CIOS/normalize
-    temps as the single-multiply kernel.  Sizes the batch tile."""
-    return (1 << window) + LIVE_U32_ARRAYS
+    temps as the single-multiply kernel and the per-lane n and R^2
+    blocks.  Sizes the batch tile."""
+    return (1 << window) + LIVE_U32_ARRAYS + LANE_ROW_ARRAYS
 
 
-def make_ladder_kernel(m: int, n0p: int, window: int, nwin: int):
+def make_ladder_kernel(m: int, window: int, nwin: int):
     """Fused full-ladder windowed modexp kernel body.
 
     One program owns a (TB, m) residue block and runs the ENTIRE k-ary
@@ -157,20 +163,28 @@ def make_ladder_kernel(m: int, n0p: int, window: int, nwin: int):
     residue/modulus/table never leave VMEM.  Per-lane exponents arrive
     as a (TB, nwin) array of window values (MSB-first, each < 2**w);
     they only ever feed the one-hot select masks, never control flow,
-    so the ladder is constant-time in structure.  w, nwin, m, n0p are
-    all baked (one specialization per modulus/exponent geometry)."""
+    so the ladder is constant-time in structure.  Each lane's modulus n,
+    R^2 mod n and n0p arrive beside it ((TB, m), (TB, m), (TB, 1)), and
+    the Montgomery form of 1, R mod n, is REDC(R^2 * 1) computed here:
+    one multiply more than the ~nbits*(1+1/w) of the ladder, and no
+    operand more.  Only w, nwin and m are baked."""
     nt = 1 << window
 
-    def ladder_kernel(base_ref, win_ref, n_ref, r2_ref, one_ref, out_ref):
+    def ladder_kernel(base_ref, win_ref, n_ref, r2_ref, n0p_ref, out_ref):
         base = base_ref[...]                      # (TB, m) digits < 2**16
         wins = win_ref[...]                       # (TB, nwin) window values
-        n = n_ref[...]                            # (1, m) modulus digits
+        n = n_ref[...]                            # (TB, m) modulus digits
+        n0p = n0p_ref[...]                        # (TB, 1) -n^{-1} mod B
+        r2 = r2_ref[...]                          # (TB, m) R^2 mod n
 
         def mm(x, y):
             return mont_mul_block(x, y, n, n0p)
 
-        x = mm(base, jnp.broadcast_to(r2_ref[...], base.shape))   # to Mont
-        table = [jnp.broadcast_to(one_ref[...], base.shape), x]
+        plain_one = jnp.broadcast_to(
+            (jax.lax.broadcasted_iota(U32, (1, m), 1) == 0).astype(U32),
+            base.shape)
+        x = mm(base, r2)                          # to Montgomery form
+        table = [mm(r2, plain_one), x]            # R mod n, then x
         for _ in range(2, nt):
             table.append(mm(table[-1], x))
 
@@ -183,34 +197,34 @@ def make_ladder_kernel(m: int, n0p: int, window: int, nwin: int):
         res, _ = jax.lax.fori_loop(
             1, nwin, win_step,
             (select_window(table, wins), rotate_down(wins)))
-        plain_one = (jax.lax.broadcasted_iota(U32, (1, m), 1) == 0)
-        out_ref[...] = mm(res, jnp.broadcast_to(plain_one.astype(U32),
-                                                base.shape))      # exit Mont
+        out_ref[...] = mm(res, plain_one)         # exit Montgomery form
 
     return ladder_kernel
 
 
 @functools.lru_cache(maxsize=64)
-def make_ladder_call(batch_tile: int, m: int, grid: int, n0p: int,
-                     window: int, nwin: int, interpret: bool):
+def make_ladder_call(batch_tile: int, m: int, grid: int, window: int,
+                     nwin: int, interpret: bool):
     """pallas_call for the fused full-ladder windowed modexp.
 
-    Inputs: base (grid*TB, m), window values (grid*TB, nwin), and the
-    (1, m) modulus / R^2 / R-mod-n rows broadcast to every program.
-    Output: (grid*TB, m) digits of base**e mod n.
+    Inputs: base (grid*TB, m), window values (grid*TB, nwin), and each
+    lane's modulus (grid*TB, m), R^2 mod n (grid*TB, m) and n0p
+    (grid*TB, 1).  Output: (grid*TB, m) digits of base**e mod n.  Five
+    operands: the call's HLO instruction is ``_ladder_call/5`` on the
+    device trace.
     """
+    rows = pl.BlockSpec((batch_tile, m), lambda i: (i, 0))
     return pl.pallas_call(
-        make_ladder_kernel(m, n0p, window, nwin),
+        make_ladder_kernel(m, window, nwin),
         # no name=: it would rename the HLO instruction (_ladder_call)
         # that the benchmark's kernel metric keys on; kernel_name stays
         # the function's name, ladder_kernel
         grid=(grid,),
-        in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
+        in_specs=[rows,
                   pl.BlockSpec((batch_tile, nwin), lambda i: (i, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
+                  rows, rows,
+                  pl.BlockSpec((batch_tile, 1), lambda i: (i, 0))],
+        out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((grid * batch_tile, m), U32),
         interpret=interpret,
     )
@@ -398,21 +412,20 @@ def make_barrett_ladder_call(batch_tile: int, m: int, grid: int,
 
 
 @functools.lru_cache(maxsize=64)
-def make_call(batch_tile: int, m: int, grid: int, n0p: int,
-              interpret: bool):
+def make_call(batch_tile: int, m: int, grid: int, interpret: bool):
     """pallas_call for the fused Montgomery multiply.
 
-    Inputs: a, b (grid*TB, m) digit arrays and the (1, m) modulus block
-    (broadcast to every program).  Output: (grid*TB, m) digits < n.
+    Inputs: a, b (grid*TB, m) digit arrays, each lane's modulus
+    (grid*TB, m) and n0p (grid*TB, 1).  Output: (grid*TB, m) digits < n.
     """
+    rows = pl.BlockSpec((batch_tile, m), lambda i: (i, 0))
     return pl.pallas_call(
-        make_mont_kernel(m, n0p),
+        make_mont_kernel(),
         name="dot_modmul_mont",
         grid=(grid,),
-        in_specs=[pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
-                  pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((batch_tile, m), lambda i: (i, 0)),
+        in_specs=[rows, rows, rows,
+                  pl.BlockSpec((batch_tile, 1), lambda i: (i, 0))],
+        out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((grid * batch_tile, m), U32),
         interpret=interpret,
     )

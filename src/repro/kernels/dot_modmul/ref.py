@@ -22,17 +22,25 @@ def mont_mul_int_ref(a: int, b: int, n: int, m: int) -> int:
     return (a * b * pow(R, -1, n)) % n
 
 
+def _per_lane(n, lanes: int) -> list:
+    """One modulus for every lane (an int), or a sequence of one a lane."""
+    ns = [n] * lanes if isinstance(n, int) else [int(v) for v in n]
+    assert len(ns) == lanes, (len(ns), lanes)
+    return ns
+
+
 def mont_mul_ref(a_digits: np.ndarray, b_digits: np.ndarray,
-                 n: int) -> np.ndarray:
-    """(batch, m) digit arrays -> (batch, m) digits of a*b*R^{-1} mod n."""
+                 n) -> np.ndarray:
+    """(batch, m) digit arrays -> (batch, m) digits of a*b*R^{-1} mod n;
+    ``n`` is one modulus or a sequence of one per lane."""
     a_digits = np.asarray(a_digits)
     b_digits = np.asarray(b_digits)
     m = a_digits.shape[-1]
     outs = []
-    for i in range(a_digits.shape[0]):
+    for i, ni in enumerate(_per_lane(n, a_digits.shape[0])):
         x = L.limbs_to_int(a_digits[i], DIGIT_BITS)
         y = L.limbs_to_int(b_digits[i], DIGIT_BITS)
-        outs.append(L.int_to_limbs(mont_mul_int_ref(x, y, n, m),
+        outs.append(L.int_to_limbs(mont_mul_int_ref(x, y, ni, m),
                                    m, DIGIT_BITS))
     return np.stack(outs)
 
@@ -49,14 +57,15 @@ def mod_exp_ref(base_digits: np.ndarray, e: int, n: int) -> np.ndarray:
 
 
 def mod_exp_ref_lanes(base_digits: np.ndarray, exps: list[int],
-                      n: int) -> np.ndarray:
-    """Per-lane exponent oracle for the batched-exponent ladder variant:
-    lane i computes base[i] ** exps[i] mod n (host pow, exact)."""
+                      n) -> np.ndarray:
+    """Per-lane oracle for the batched-exponent ladder: lane i computes
+    base[i] ** exps[i] mod n_i (host pow, exact), where ``n`` is one
+    modulus or a sequence of one per lane."""
     base_digits = np.asarray(base_digits)
     m = base_digits.shape[-1]
     assert base_digits.shape[0] == len(exps)
     outs = []
-    for i, e in enumerate(exps):
+    for i, (e, ni) in enumerate(zip(exps, _per_lane(n, len(exps)))):
         x = L.limbs_to_int(base_digits[i], DIGIT_BITS)
-        outs.append(L.int_to_limbs(pow(x, int(e), n), m, DIGIT_BITS))
+        outs.append(L.int_to_limbs(pow(x, int(e), ni), m, DIGIT_BITS))
     return np.stack(outs)

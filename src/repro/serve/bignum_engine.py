@@ -16,11 +16,17 @@ itself milliseconds):
   ``exp_bucket_bits``), so arbitrary natural widths collapse onto a few
   padded shapes.  RSA-key ops keep their natural width: the key set is
   finite, so it is already a finite shape set.
-* **Per-modulus program cache** -- the Pallas ladder bakes the
-  Montgomery constant n0p statically (kernels/dot_modmul/ops.py), so a
-  modulus CANNOT be traced data; the jit cache therefore keys on
-  ``(op, width-bucket, exp-bucket, modulus)`` and ``warm()``
-  pre-compiles the registered modulus/key set before traffic.
+* **One program per key width** -- ``rsa_sign`` / ``rsa_verify`` lanes
+  carry their own keys: the Montgomery ladder takes every lane's modulus
+  and constants as run-time operands (kernels/dot_modmul), so the bucket
+  key is ``(op, bits)`` and requests under different keys of one width
+  share batches and one compiled program.  The keys live in a table on
+  the device, one per width (``register_keys``, one row a key, grown in
+  powers of two); a flush sends the lanes' key indices and the program
+  gathers their rows.  ``rsa_decrypt`` (two CRT moduli a key) and raw
+  ``mod_exp`` keep one bucket and one program per modulus:
+  ``(op, width-bucket, exp-bucket, modulus)``.  ``warm()`` pre-compiles
+  before traffic; register every key first and one compile serves them.
 
 Batching policy (continuous): requests queue per bucket key; a bucket
 flushes when it reaches ``slots`` lanes (full flush) or when its oldest
@@ -67,12 +73,15 @@ Spans and counters
 ------------------
 Every ``submit`` runs in a ``serve.submit`` span and every flush in a
 ``serve.flush`` span (args: a per-engine ``flush`` sequence number,
-``op``, ``bits``, ``lanes``, ``reason``, the served ``rids``, and
-``compiled=True`` when the jit cache missed), which holds
+``op``, ``bits``, ``lanes``, ``keys`` -- distinct keys or moduli among
+the lanes --, ``reason``, the served ``rids``, and ``compiled=True``
+when the jit cache missed), which holds
 ``serve.assemble`` (the padded batch), ``serve.dispatch`` (the jitted
 call), ``serve.device_wait`` (blocking on the result) and
-``serve.unpack`` (checks and hand-out).  ``EngineStats`` adds up the
-engine's own waits on its own clock: see its docstring.
+``serve.unpack`` (checks and hand-out).  Key registration and the
+table's upload run in a ``serve.keys`` span (args ``keys``, ``rows``).
+``EngineStats`` adds up the engine's own waits on its own clock: see its
+docstring.
 
 All arithmetic goes through the ``repro.api`` facade; this module never
 imports the digit-radix internals.
@@ -95,9 +104,11 @@ from repro.resilience import inject as _inject
 from repro.resilience import selfcheck as _selfcheck
 
 OPS = ("mod_exp", "rsa_sign", "rsa_verify", "rsa_decrypt")
+KEYED_OPS = ("rsa_sign", "rsa_verify")    # a key a lane, from the key table
 
-# (op, width bucket bits, exp bucket bits or None, modulus / key.n)
-BucketKey = Tuple[str, int, Optional[int], int]
+# (op, width bucket bits, exp bucket bits or None, modulus / key.n; None
+# for KEYED_OPS, whose lanes carry their own keys)
+BucketKey = Tuple[str, int, Optional[int], Optional[int]]
 
 
 @dataclasses.dataclass
@@ -141,6 +152,7 @@ class EngineStats:
     programs: int = 0         # distinct compiled entry points
     served: int = 0
     batches: int = 0
+    batch_keys: int = 0       # per flush: distinct keys (moduli) of its lanes
     flush_full: int = 0
     flush_deadline: int = 0
     padded_lanes: int = 0
@@ -173,10 +185,15 @@ class BignumEngine:
         self._deadlines: Dict[BucketKey, float] = {}
         self._fns: Dict[BucketKey, Callable] = {}
         self._ctxs: Dict[Tuple[int, int], object] = {}
+        # KEYED_OPS: per key width, the keys in table order, each key's
+        # row, and the device-resident table (api.KeyLanes)
+        self._keys: Dict[int, List[api.RSAKey]] = {}
+        self._key_row: Dict[api.RSAKey, int] = {}
+        self._tables: Dict[int, api.KeyLanes] = {}
         # the zero-retrace contract arms once warm() completes: any jit
         # body execution after that is an unexpected retrace
         self._warmed = False
-        self._warmed_keys: set = set()      # warm() idempotence
+        self._warmed_keys: set = set()      # warm() idempotence, by bucket
         self._degraded: Dict[BucketKey, str] = {}   # bucket -> demoted tier
         self._expect_trace = False          # a degrade's recompile is legit
         self._closed = False
@@ -200,6 +217,8 @@ class BignumEngine:
             return (req.op, nbits, ebits, req.modulus)
         if req.key is None:
             raise ValueError(f"{req.op} requests need key=")
+        if req.op in KEYED_OPS:
+            return (req.op, req.key.bits, None, None)
         return (req.op, req.key.bits, None, req.key.n)
 
     def _ctx(self, modulus: int, nbits: int):
@@ -207,6 +226,37 @@ class BignumEngine:
         if k not in self._ctxs:
             self._ctxs[k] = api.mod_setup(modulus, nbits)
         return self._ctxs[k]
+
+    # -- key tables -------------------------------------------------------
+
+    def register_keys(self, keys) -> None:
+        """Add RSA keys to the device-resident key table of their width,
+        which every ``rsa_sign`` / ``rsa_verify`` program of that width
+        reads.  The table holds one row a key (modulus, R^2 mod n, n0p,
+        the bits of d and e) and grows in powers of two: a registration
+        that outgrows it changes the programs' input shape, so the next
+        flush compiles anew (register every key before ``warm()``).
+        Registered keys are kept; a flush registers its lanes' keys."""
+        new = [k for k in dict.fromkeys(keys) if k not in self._key_row]
+        if not new:
+            return
+        for bits in sorted({k.bits for k in new}):
+            rows = self._keys.setdefault(bits, [])
+            for k in new:
+                if k.bits == bits:
+                    self._key_row[k] = len(rows)
+                    rows.append(k)
+            size = 1 << (len(rows) - 1).bit_length()
+            with obs.span("serve.keys", keys=len(rows), rows=size):
+                table = api.key_lanes(rows + rows[:1] * (size - len(rows)))
+                self._tables[bits] = jax.device_put(table)
+
+    def _warm_mark(self, bkey: BucketKey):
+        """What a warmed program is valid for: its bucket, and for
+        KEYED_OPS the rows of the key table it was compiled at."""
+        if bkey[0] in KEYED_OPS:
+            return bkey, self._tables[bkey[1]].n0p.shape[0]
+        return bkey
 
     # -- compiled-program cache -------------------------------------------
 
@@ -234,12 +284,13 @@ class BignumEngine:
                                        crt=_crt)
         else:
             f = api.rsa_sign if op == "rsa_sign" else api.rsa_verify
-            key = sample.key
 
-            def body(base, _f=f, _key=key):
+            def body(base, rows, table, _f=f):
+                # the table is an argument, not a constant of the
+                # program: one program serves every key of the width
                 stats.traces += 1
                 engine._on_trace(op, nbits)
-                return _f(base, _key, backend=backend)
+                return _f(base, table.take(rows), backend=backend)
         fn = jax.jit(body)
         self._fns[bkey] = fn
         stats.programs += 1
@@ -277,6 +328,11 @@ class BignumEngine:
                         for r in reqs]
                 rows += [rows[0]] * (slots - len(reqs))
                 args += (np.stack(rows),)
+            elif op in KEYED_OPS:
+                self.register_keys([r.key for r in reqs])
+                rows = [self._key_row[r.key] for r in reqs]
+                rows += rows[:1] * (slots - len(reqs))   # pad: lane 0's key
+                args += (np.asarray(rows, np.int32), self._tables[nbits])
         with obs.span("serve.dispatch"):
             out = fn(*args)
         t0 = time.perf_counter()
@@ -369,13 +425,14 @@ class BignumEngine:
     def warm(self, op: str, *, modulus: Optional[int] = None,
              exponent: Optional[int] = None,
              key: Optional[api.RSAKey] = None) -> None:
-        """Pre-compile the program for one (op, bucket, modulus) before
-        traffic (for mod_exp, ``exponent`` is a representative value --
-        only its quantized width matters).  Serving a warmed bucket
-        never traces again: snapshot ``stats.traces`` after warming to
-        assert the zero-retrace property (the runtime form of the same
-        contract is the retrace alarm, armed once any warm() finishes
-        -- see ``_on_trace``).
+        """Pre-compile the program for one bucket before traffic (for
+        mod_exp, ``exponent`` is a representative value -- only its
+        quantized width matters; for rsa_sign / rsa_verify, ``key`` is
+        registered and the program serves every registered key of its
+        width).  Serving a warmed bucket never traces again: snapshot
+        ``stats.traces`` after warming to assert the zero-retrace
+        property (the runtime form of the same contract is the retrace
+        alarm, armed once any warm() finishes -- see ``_on_trace``).
 
         Idempotent per bucket (re-warming a warmed key is a no-op, not a
         fresh trace).  A bucket whose warm-up raises propagates the
@@ -389,7 +446,10 @@ class BignumEngine:
         sample = BignumRequest(rid=-1, op=op, value=np.zeros(1, np.uint32),
                                modulus=modulus, exponent=exponent, key=key)
         bkey = self.bucket_key(sample)
-        if bkey in self._warmed_keys:
+        if op in KEYED_OPS:
+            self.register_keys([key])
+        mark = self._warm_mark(bkey)
+        if mark in self._warmed_keys:
             return
         self._warmed = False            # warming traces are expected
         try:
@@ -403,7 +463,7 @@ class BignumEngine:
                 except Exception as exc:            # noqa: BLE001
                     if not self._degrade(bkey, exc, "warm"):
                         raise
-            self._warmed_keys.add(bkey)
+            self._warmed_keys.add(mark)
         finally:
             self._warmed = True
 
@@ -499,7 +559,8 @@ class BignumEngine:
         traces0 = self.stats.traces
         self._flush_seq += 1
         with obs.span("serve.flush", flush=self._flush_seq, op=bkey[0],
-                      bits=bkey[1], lanes=len(reqs), reason=reason,
+                      bits=bkey[1], lanes=len(reqs),
+                      keys=_distinct_keys(reqs), reason=reason,
                       rids=" ".join(str(r.rid) for r in reqs)) as args:
             try:
                 out = self._run_batch(bkey, reqs)
@@ -544,6 +605,7 @@ class BignumEngine:
         st = self.stats
         st.served += len(reqs)
         st.batches += 1
+        st.batch_keys += _distinct_keys(reqs)
         st.padded_lanes += self.cfg.slots - len(reqs)
         if reason == "full":
             st.flush_full += 1
@@ -606,6 +668,11 @@ class BignumEngine:
         for q in reqs:
             wait = max(0.0, now - q.arrival) if now is not None else 0.0
             hist.observe(wait + dt, op=bkey[0], bits=bkey[1])
+
+
+def _distinct_keys(reqs: List[BignumRequest]) -> int:
+    """Distinct keys (rsa_*) or moduli (mod_exp) among a batch's lanes."""
+    return len({(r.modulus, r.key) for r in reqs})
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,8 @@ oracle, plus backend-dispatch agreement and RSA round-trips through the
 pallas path.  The oracle (kernels/dot_modmul/ref.py) is independent of
 all jnp code, so a kernel bug and a core/modular.py bug cannot cancel.
 """
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -86,6 +88,64 @@ def test_mod_exp_kernel_per_lane_exponents():
     out = np.asarray(ops.dot_mod_exp(_digit_batch(xs, ctx.m), eb, ctx))
     for i, (x, e) in enumerate(zip(xs, es)):
         assert L.limbs_to_int(out[i], 16) == pow(x, e, n), i
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_keys(nbits, count):
+    return [R.generate_key(bits=nbits, seed=500 + k) for k in range(count)]
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp", "reference"])
+def test_mod_exp_per_lane_keys_vs_pow(backend):
+    """8 lanes under 8 different 512-bit keys in one batch: lane i is
+    m_i^d_i mod n_i (the Montgomery ladder with per-lane moduli)."""
+    keys = _lane_keys(512, 8)
+    lanes = R.key_lanes(keys)
+    ms = [v % k.n for v, k in zip(L.random_bigints(RNG, 8, 512), keys)]
+    out = np.asarray(M.mod_exp(jnp.asarray(_digit_batch(ms, 32)),
+                               lanes.d_bits, lanes.ctx, backend=backend))
+    for i, (m, k) in enumerate(zip(ms, keys)):
+        assert L.limbs_to_int(out[i], 16) == pow(m, k.d, k.n), i
+
+
+def test_mont_mul_kernel_per_lane_moduli():
+    ns = [_odd_modulus(192) for _ in range(9)]
+    ctx = M.mont_lanes(ns, 192)
+    xs = [v % n for v, n in zip(L.random_bigints(RNG, 9, 192), ns)]
+    ys = [v % n for v, n in zip(L.random_bigints(RNG, 9, 192), ns)]
+    a, b = _digit_batch(xs, ctx.m), _digit_batch(ys, ctx.m)
+    want = ref.mont_mul_ref(a, b, ns)
+    for backend in ("pallas", "jnp", "reference"):
+        out = np.asarray(M.mont_mul(a, b, ctx, backend=backend))
+        np.testing.assert_array_equal(out, want, backend)
+
+
+@pytest.mark.parametrize("backend", ["barrett", "barrett_fused"])
+def test_barrett_refuses_per_lane_moduli(backend):
+    ns = [_odd_modulus(128) for _ in range(4)]
+    ctx = M.mont_lanes(ns, 128)
+    x = jnp.asarray(_digit_batch([3, 5, 7, 11], ctx.m))
+    with pytest.raises(ValueError, match="per-lane"):
+        M.mod_exp(x, jnp.asarray(M.exp_bits_msb(65537)), ctx,
+                  backend=backend)
+    with pytest.raises(ValueError, match="per-lane"):
+        M.mod_mul(x, x, ctx, backend=backend)
+
+
+def test_rsa_sign_with_a_key_per_lane():
+    """api.rsa_sign takes keys one a lane (KeyLanes), and verify gives
+    the messages back under the same keys."""
+    from repro import api
+    keys = _lane_keys(256, 5)
+    lanes = api.key_lanes(keys)
+    ms = [v % k.n for v, k in zip(L.random_bigints(RNG, 5, 256), keys)]
+    sigs = api.rsa_sign(api.to_limbs(ms, 256), lanes, backend="pallas")
+    assert api.from_limbs(sigs) == [pow(m, k.d, k.n)
+                                    for m, k in zip(ms, keys)]
+    back = api.rsa_verify(sigs, lanes, backend="pallas")
+    assert api.from_limbs(back) == ms
+    with pytest.raises(ValueError, match="one width"):
+        api.key_lanes([keys[0], R.generate_key(bits=128, seed=1)])
 
 
 def test_backend_dispatch_agreement():

@@ -281,7 +281,7 @@ def test_engine_flush_populates_latency_histogram():
     assert names.count("serve.submit") == SMALL.slots
     (sp,) = [sp for sp in obs.spans.spans() if sp["name"] == "serve.flush"]
     assert sp["args"] == {"flush": 1, "op": "mod_exp", "bits": 96,
-                          "lanes": SMALL.slots, "reason": "full",
+                          "lanes": SMALL.slots, "keys": 1, "reason": "full",
                           "rids": "0 1 2 3"}
 
 
@@ -359,6 +359,25 @@ def test_multiple_warms_do_not_false_alarm():
 
 
 # ---------------------------------------------------------------------------
+def test_engine_key_table_span_and_flush_keys():
+    keys = [api.generate_key(96, seed=s) for s in (1, 2, 3)]
+    eng = _stub_engine()
+    with _observing():
+        eng.register_keys(keys[:2])
+        eng.register_keys(keys[:2])              # registered: no span
+        eng.register_keys(keys)                  # 3 keys, a 4-row table
+        for i, k in enumerate([keys[0], keys[2], keys[0], keys[1]]):
+            eng.submit(BE.BignumRequest(rid=i, op="rsa_sign",
+                                        value=np.zeros(3, np.uint32),
+                                        key=k), now=0.0)
+    spans = obs.spans.spans()
+    assert [sp["args"] for sp in spans if sp["name"] == "serve.keys"] == [
+        {"keys": 2, "rows": 2}, {"keys": 3, "rows": 4}]
+    (sp,) = [sp for sp in spans if sp["name"] == "serve.flush"]
+    assert sp["args"]["keys"] == 3 and sp["args"]["lanes"] == 4
+    assert eng.stats.batch_keys == 3
+
+
 # serving engine: spans on a profiler trace, and its own wait counters
 # ---------------------------------------------------------------------------
 

@@ -61,10 +61,18 @@ def test_bucket_key_quantizes_widths():
     assert k80[:3] == k90[:3] == ("mod_exp", 96, 16)
     assert k80 != k90                    # modulus is part of the key
     assert k150[1] == 160
-    key = api.generate_key(96, seed=5)
-    krsa = eng.bucket_key(BE.BignumRequest(
-        rid=3, op="rsa_sign", value=np.zeros(3, np.uint32), key=key))
-    assert krsa == ("rsa_sign", 96, None, key.n)   # natural width
+    key, other = api.generate_key(96, seed=5), api.generate_key(96, seed=6)
+
+    def rsa_key(op, k):
+        return eng.bucket_key(BE.BignumRequest(
+            rid=3, op=op, value=np.zeros(3, np.uint32), key=k))
+    # sign and verify lanes carry their own keys: one bucket per width
+    assert rsa_key("rsa_sign", key) == ("rsa_sign", 96, None, None)
+    assert rsa_key("rsa_sign", other) == rsa_key("rsa_sign", key)
+    assert rsa_key("rsa_verify", other) == ("rsa_verify", 96, None, None)
+    # CRT decryption keeps one bucket per key
+    assert rsa_key("rsa_decrypt", key) == ("rsa_decrypt", 96, None, key.n)
+    assert rsa_key("rsa_decrypt", other) != rsa_key("rsa_decrypt", key)
 
 
 def test_unknown_op_message():
@@ -206,6 +214,80 @@ def test_rsa_ops_through_engine():
     assert int(api.from_limbs(dec_req.result)) == msg
     # three ops -> three distinct programs, all padded singleton batches
     assert eng.stats.programs == 3 and eng.stats.padded_lanes == 3
+
+
+def _zipf_keys(nkeys, count, seed):
+    rng = random.Random(seed)
+    weights = [1.0 / (k + 1) for k in range(nkeys)]
+    return rng.choices(range(nkeys), weights=weights, k=count)
+
+
+@pytest.mark.parametrize("register_first", [True, False])
+def test_many_keys_share_one_bucket_and_program(register_first):
+    """A Zipf-skewed stream over 16 keys of one width: one bucket, one
+    compiled program, no trace after warm(), padded batches, and every
+    signature equal to m^d mod n under its own request's key."""
+    keys = [api.generate_key(128, seed=100 + k) for k in range(16)]
+    cfg = ServeConfig(bucket_bits=(128,), exp_bucket_bits=(256,),
+                      slots=4, max_wait_s=0.01)
+    eng = BE.BignumEngine(cfg, backend="jnp")
+    if register_first:
+        eng.register_keys(keys)
+        eng.warm("rsa_sign", key=keys[0])
+    else:                           # one warm() a key: the table grows
+        for k in keys:
+            eng.warm("rsa_sign", key=k)
+    assert eng.stats.programs == 1
+    traces = eng.stats.traces
+    assert traces == (1 if register_first else 5)   # rows 1, 2, 4, 8, 16
+    rng = random.Random(7)
+    picks = _zipf_keys(16, 14, seed=3)
+    reqs = []
+    for i, k in enumerate(picks):
+        m = rng.randrange(1, keys[k].n)
+        reqs.append(BE.BignumRequest(rid=i, op="rsa_sign",
+                                     value=api.to_limbs(m, 128),
+                                     key=keys[k]))
+    done = []
+    for r in reqs:
+        done += eng.submit(r, now=0.0)
+    while eng.pending():
+        done += eng.drain_one()
+    assert sorted(r.rid for r in done) == list(range(len(reqs)))
+    for r in reqs:
+        m = int(api.from_limbs(r.value))
+        assert int(api.from_limbs(r.result)) == pow(m, r.key.d, r.key.n)
+    st = eng.stats
+    assert st.traces == traces and st.programs == 1
+    assert st.batches == 4 and st.padded_lanes == 2   # 14 over 4 slots
+    assert st.batch_keys == sum(len(set(picks[i:i + 4]))
+                                for i in range(0, 14, 4))
+    # verification of the signatures rides one more program
+    eng.warm("rsa_verify", key=keys[0])
+    for r in reqs:
+        eng.submit(BE.BignumRequest(rid=100 + r.rid, op="rsa_verify",
+                                    value=r.result, key=r.key), now=0.0)
+    while eng.pending():
+        for v in eng.drain_one():
+            assert int(api.from_limbs(v.result)) == int(
+                api.from_limbs(reqs[v.rid - 100].value))
+    assert eng.stats.programs == 2
+
+
+def test_batch_keys_counts_distinct_keys_per_flush():
+    eng = _stub(BE.BignumEngine(SMALL))
+    keys = [api.generate_key(96, seed=s) for s in (21, 22, 23)]
+    lanes = [0, 1, 0, 2,   1, 1, 1, 1,   2]      # 3 keys, 1 key, 1 key
+    for i, k in enumerate(lanes):
+        eng.submit(BE.BignumRequest(rid=i, op="rsa_sign",
+                                    value=np.zeros(3, np.uint32),
+                                    key=keys[k]), now=0.0)
+    eng.drain_one()
+    n = _odd(80)                                  # mod_exp: one modulus
+    eng.submit(_mod_exp_req(9, n, e=5), now=0.0)
+    eng.drain_one()
+    assert eng.stats.batches == 4
+    assert eng.stats.batch_keys == 3 + 1 + 1 + 1
 
 
 # ---------------------------------------------------------------------------

@@ -56,10 +56,11 @@ def _ladder(s, bits, batch=8):
     nwin = -(-bits // w)
     tb = tiling.batch_tile(m, batch, budget=tiling.budget_words(
         modmul_kernel.ladder_live_arrays(w)), max_tile=modmul_kernel.MAX_TILE)
-    row = _shape(s, 1, m)
+    # base, window values, and each lane's modulus, R^2 mod n and n0p
     return modmul_ops._ladder_call.lower(
-        _shape(s, batch, m), _shape(s, batch, nwin), row, row, row,
-        tb=tb, n0p=0x1235, window=w, interpret=False)
+        _shape(s, batch, m), _shape(s, batch, nwin), _shape(s, batch, m),
+        _shape(s, batch, m), _shape(s, batch, 1),
+        tb=tb, window=w, interpret=False)
 
 
 def _barrett_ladder(s, bits, batch=8):
@@ -157,6 +158,17 @@ def _rsa_sign_512(s):
     return jax.jit(lambda b: api.rsa_sign(b, key)).lower(_shape(s, 8, 16))
 
 
+def _rsa_sign_512_keys(s):
+    # the serving engine's program: a table of keys as an argument, each
+    # lane's key gathered by its row
+    table = api.key_lanes([api.generate_key(512, seed=k) for k in (1, 2, 3)])
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), table)
+    rows = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=s)
+    return jax.jit(lambda b, r, t: api.rsa_sign(b, t.take(r))).lower(
+        _shape(s, 8, 16), rows, shapes)
+
+
 KEYED = {   # program: (build, the kernels' <instruction>/<operand count>)
     "mul-1024": (lambda s: jax.jit(api.mul).lower(_shape(s, 8, 32),
                                                   _shape(s, 8, 32)),
@@ -166,6 +178,7 @@ KEYED = {   # program: (build, the kernels' <instruction>/<operand count>)
                  # ntt_mul, one per prime, then the CRT recombination
                  ["_call/4", "_call/4", "crt_combine/2"]),
     "rsa_sign-512": (_rsa_sign_512, ["_ladder_call/5"]),
+    "rsa_sign-512-keys": (_rsa_sign_512_keys, ["_ladder_call/5"]),
 }
 
 
